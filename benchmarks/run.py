@@ -30,6 +30,9 @@ def main() -> None:
     args = ap.parse_args()
     only = [s.strip() for s in args.only.split(",") if s.strip()] or SUITES
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     results: dict[str, dict] = {}
     for suite in only:
         t0 = time.time()

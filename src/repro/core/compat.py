@@ -1,56 +1,32 @@
-"""Version guards for the JAX APIs this codebase uses across 0.4.x → 0.6.x.
+"""Mesh helpers over the installed JAX's sharding API (jax 0.9).
 
-Installed JAX may be as old as 0.4.37, which lacks
-`jax.sharding.get_abstract_mesh`, `jax.sharding.AxisType`, and the
-`axis_types=` kwarg of `jax.make_mesh`. Callers go through these shims so
-the new-API path is taken when available and the legacy path (thread-local
-physical mesh, plain `Mesh` construction) otherwise.
+`Engine(mesh=...)` and the launch code go through these three calls so
+the ambient-mesh mechanics live in one place.
 """
 
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 
 def get_ambient_mesh():
-    """The mesh visible at trace time: the abstract mesh on new JAX, the
-    thread-local physical mesh (set by `with mesh:`) on 0.4.x. Either way
-    the result exposes `.axis_names` and a dict-like `.shape`; with no
-    ambient mesh, `axis_names` is empty."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    from jax.interpreters import pxla
-    return pxla.thread_resources.env.physical_mesh
+    """The abstract mesh visible at trace time. It exposes `.axis_names`
+    and a dict-like `.shape`; with no ambient mesh, `axis_names` is
+    empty."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def mesh_context(mesh):
     """Context manager installing `mesh` as the ambient mesh for traces
-    opened inside it: `jax.sharding.use_mesh` where it exists (sets the
-    abstract mesh new `get_abstract_mesh` reports), the mesh's own
-    thread-local context on 0.4.x (what `get_ambient_mesh` falls back
-    to). Lets `shard_hint` constraints fire inside serving dispatches
-    without callers caring which API generation is installed."""
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
+    opened inside it (`jax.set_mesh`), so `shard_hint` constraints fire
+    inside serving dispatches. A bare `with mesh:` no longer sets the
+    abstract mesh: under it `shard_hint` silently did nothing."""
+    return jax.set_mesh(mesh)
 
 
 def make_compat_mesh(shape, axis_names, *, devices=None):
-    """`jax.make_mesh` with explicit-Auto axis types where supported.
-
-    0.4.x `jax.make_mesh` has no `axis_types` kwarg (all axes are Auto
-    implicitly, which is exactly what we want); some very old versions
-    lack `jax.make_mesh` entirely, where a reshaped `Mesh` is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    make = getattr(jax, "make_mesh", None)
-    if make is not None:
-        if axis_type is not None:
-            return make(shape, axis_names, devices=devices,
-                        axis_types=(axis_type.Auto,) * len(axis_names))
-        return make(shape, axis_names, devices=devices)
-    devs = np.asarray(devices if devices is not None
-                      else jax.devices()[: int(np.prod(shape))])
-    return jax.sharding.Mesh(devs.reshape(shape), axis_names)
+    """`jax.make_mesh` with every axis Auto: GSPMD derives the layouts of
+    intermediates from the committed operands and `shard_hint`s."""
+    return jax.make_mesh(shape, axis_names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

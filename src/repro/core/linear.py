@@ -26,10 +26,12 @@ weight-copy cost (both executables alias the same buffers).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import nestedfp as nf
 from repro.core import quant
@@ -41,16 +43,22 @@ Mode = Literal["fp16", "fp8"]
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class NestedLinearParams:
-    """Weight (K,N) in NestedFP form + optional bias (N,)."""
+    """Weight (K,N) in NestedFP form + optional bias (N,).
+
+    `spec` (static pytree aux data) is the weight's (K, N) placement on a
+    serving mesh's "model" axis, e.g. (None, "model") for a column-
+    parallel projection. `serving/shard.py` records it when it commits
+    the weights; None means unsharded."""
     weight: nf.NestedTensor
     bias: jax.Array | None
+    spec: tuple | None = None
 
     def tree_flatten(self):
-        return (self.weight, self.bias), None
+        return (self.weight, self.bias), self.spec
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, spec=aux)
 
     @classmethod
     def create(cls, key, in_features: int, out_features: int,
@@ -72,10 +80,31 @@ class NestedLinearParams:
         return self.weight.shape
 
 
+def _on_mesh(gemm, mesh, spec, x, weights, extra=()):
+    """Run `gemm(x, *weights, *extra)` per shard of a serving mesh. A
+    pallas_call is opaque to GSPMD, which would gather the whole weight
+    onto every chip; shard_map hands each chip its own (K, N) slice
+    instead. Row-parallel weights (K on "model") take the matching slice
+    of x and all-reduce the partial products; extra operands (FP8 scales)
+    are replicated."""
+    k_ax, n_ax = spec
+    lead = (None,) * (x.ndim - 1)
+
+    def local(xx, *ops_in):
+        y = gemm(xx, *ops_in)
+        return jax.lax.psum(y, "model") if k_ax else y
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(*lead, k_ax),) + (P(k_ax, n_ax),) * len(weights)
+        + tuple(P() for _ in extra),
+        out_specs=P(*lead, n_ax), check_vma=False)(x, *weights, *extra)
+
+
 def nested_linear(params: NestedLinearParams, x: jax.Array, *,
                   mode: Mode = "fp16", backend: str | None = None,
                   out_dtype=None, fast_accum: bool = False,
-                  act_quant: str = "per_tensor") -> jax.Array:
+                  act_quant: str = "per_tensor", mesh=None) -> jax.Array:
     """Apply y = x @ W (+ b) at the selected precision.
 
     x: (..., K). Returns (..., N) in out_dtype (default: x.dtype).
@@ -83,30 +112,42 @@ def nested_linear(params: NestedLinearParams, x: jax.Array, *,
     bf16 (halves tensor-parallel all-reduce bytes; serving-only trade).
     act_quant: fp8 activation scale granularity — "per_tensor" (paper
     scheme) or "per_token" (batch-invariant; module docstring).
+    mesh: the serving mesh. With a Pallas backend and a sharded
+    `params.spec`, the GEMM runs under shard_map on each chip's slice;
+    the jnp oracle is left to GSPMD.
     """
     out_dtype = out_dtype or x.dtype
     acc = jnp.bfloat16 if fast_accum else jnp.float32
+    backend = backend or ops.default_backend()
     w = params.weight
+    extra = ()
     if w.is_exception or mode == "fp16":
+        x = x.astype(jnp.float16)
         if w.is_exception:
-            y = ops.matmul_f16(x.astype(jnp.float16), w.read_f16(),
-                               backend=backend, out_dtype=acc, acc_dtype=acc)
+            weights = (w.read_f16(),)
+            gemm = functools.partial(ops.matmul_f16, backend=backend,
+                                     out_dtype=acc, acc_dtype=acc)
         else:
-            y = ops.matmul_nested_f16(x.astype(jnp.float16), w.upper, w.lower,
-                                      backend=backend, out_dtype=acc,
-                                      acc_dtype=acc)
+            weights = (w.upper, w.lower)
+            gemm = functools.partial(ops.matmul_nested_f16, backend=backend,
+                                     out_dtype=acc, acc_dtype=acc)
     elif mode == "fp8":
         if act_quant == "per_token":
-            xq, scale = quant.quantize_act_per_token(x)
+            x, scale = quant.quantize_act_per_token(x)
             # (..., 1) row scales, flattened to match the GEMM's (M, K)
             # view of x — each row's dequant is independent of the batch
             scale = scale.reshape(-1, 1)
         else:
-            xq, scale = quant.quantize_act_per_tensor(x)
-        y = ops.matmul_nested_fp8(xq, w.upper, scale, backend=backend,
-                                  out_dtype=acc, acc_dtype=acc)
+            x, scale = quant.quantize_act_per_tensor(x)
+        weights, extra = (w.upper,), (scale,)
+        gemm = functools.partial(ops.matmul_nested_fp8, backend=backend,
+                                 out_dtype=acc, acc_dtype=acc)
     else:
         raise ValueError(f"unknown mode {mode!r}")
+    if mesh is not None and params.spec is not None and backend != "ref":
+        y = _on_mesh(gemm, mesh, params.spec, x, weights, extra)
+    else:
+        y = gemm(x, *weights, *extra)
     if params.bias is not None:
         y = y + params.bias
     return y.astype(out_dtype)

@@ -87,6 +87,24 @@ def decode(upper: jax.Array, lower: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits.astype(jnp.uint16), jnp.float16)
 
 
+def f16_bits_to_f32(bits: jax.Array) -> jax.Array:
+    """f16 bit patterns held in int32 lanes (low 16 bits) -> the exact f32
+    values, using 32-bit integer and float ops only. The TPU v5e vector
+    unit and MXU have no f16 type, so Pallas kernels rebuild f16 weights
+    and KV bytes this way: rebias the 5-bit exponent into the 8-bit f32
+    field (inf/NaN map to 255), and build subnormals as mantissa * 2^-24
+    (an f32 subnormal would be flushed to zero on the TPU)."""
+    sign = (bits >> 15) & 1
+    mag = bits & 0x7FFF
+    exp = mag >> 10
+    rebias = jnp.where(exp == 31, 255 - 31, 127 - 15)
+    normal = jax.lax.bitcast_convert_type(
+        (sign << 31) | ((mag + (rebias << 10)) << 13), jnp.float32)
+    sub = (mag & 0x3FF).astype(jnp.float32) * (2.0 ** -24)
+    sub = jnp.where(sign == 1, -sub, sub)
+    return jnp.where(exp == 0, sub, normal)
+
+
 def fp8_view(upper: jax.Array) -> jax.Array:
     """Reinterpret the upper tensor as float8_e4m3fn == w * 2^8 (RNE)."""
     return jax.lax.bitcast_convert_type(upper, jnp.float8_e4m3fn)

@@ -1,9 +1,14 @@
 """Pallas TPU kernel: plain f16 GEMM — the 'vanilla CUTLASS' baseline.
 
 Identical grid/BlockSpec/accumulator structure to nestedfp16_matmul but
-with a single pre-materialized f16 weight tensor and no reconstruction
-step. The kernel-overhead benchmark (paper Fig. 7) compares the two; any
-delta is exactly the cost of the in-kernel bitwise reconstruction.
+with a single pre-materialized f16 weight tensor and no checksum step.
+The kernel-overhead benchmark (paper Fig. 7) compares the two; any delta
+is exactly the cost of the in-kernel NestedFP reconstruction.
+
+v5e cannot load f16 tiles, so the weight reaches the kernel as its high
+and low bytes (`nestedfp.split_bytes`, an XLA pass before the call) and
+is rejoined in int32 lanes to its exact f32 value — the same rebuild and
+HIGHEST-precision dot as the nested kernel, so the two agree exactly.
 """
 
 from __future__ import annotations
@@ -15,19 +20,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.nestedfp import f16_bits_to_f32, split_bytes
+from repro.kernels.nestedfp16_matmul import f16_dot
+
 DEFAULT_BLOCK = (128, 128, 256)
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
+def _kernel(x_ref, hi_ref, lo_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    bits = (hi_ref[...].astype(jnp.int32) << 8) | lo_ref[...].astype(jnp.int32)
+    acc_ref[...] += f16_dot(x_ref[...], f16_bits_to_f32(bits))
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -44,6 +51,7 @@ def f16_matmul(x: jax.Array, w: jax.Array,
     bm, bn, bk = block
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
     n_k = k // bk
+    hi, lo = split_bytes(w)
 
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),
@@ -51,9 +59,10 @@ def f16_matmul(x: jax.Array, w: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.float16), w.astype(jnp.float16))
+    )(x.astype(jnp.float16).astype(jnp.float32), hi, lo)

@@ -6,10 +6,12 @@ MXU runs at its 8-bit rate. The upper byte IS a valid float8_e4m3fn
 encoding of w*2^8, so "dequantization" is a bitcast plus one scalar
 multiply folded into the epilogue.
 
-On real TPU (v6e+) the `dot_general` below hits the native fp8 MXU path;
-on v5e the compiler upcasts tiles to bf16 in VMEM (weight HBM traffic —
-the bandwidth term that matters at serving batch sizes — is still 1
-byte/weight). Interpret mode (CPU tests) upcasts to f32.
+The kernel widens both e4m3 tiles to f32 in VMEM and runs an f32 dot
+with f32 accumulation; this is what compiles for v5e, which has no FP8
+MXU type. Every e4m3 value and every product of two is exact in f32, so
+the result equals the FP8 GEMM up to summation order. Weight HBM traffic
+— the bandwidth term that matters at serving batch sizes — is still 1
+byte/weight. Interpret mode (CPU tests) runs the same f32 math.
 
 A separate fused variant also quantizes the activation tile on the fly
 (per-tensor scale passed in SMEM), saving one full activation round-trip
@@ -38,7 +40,7 @@ def _kernel(x_ref, u_ref, scale_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     w8 = jax.lax.bitcast_convert_type(u_ref[...], jnp.float8_e4m3fn)
-    # fp8 x fp8 -> f32: native MXU on v6e; interpret upcasts.
+    # e4m3 x e4m3 widened to f32 (exact); no FP8 MXU type on v5e
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...].astype(jnp.float32), w8.astype(jnp.float32),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)

@@ -4,6 +4,9 @@ Converts an f16 weight tensor into the (upper, lower) byte pair in one
 streaming pass — used when nesting multi-GB checkpoints on device, where
 a fused kernel avoids materializing intermediate u32 tensors in HBM.
 Pure VPU work: band-split, RNE rounding with carry, byte extraction.
+
+Off the serving path: `models/convert.py` encodes in jnp. It does not
+compile for v5e, whose vector unit cannot load its f16 input tile.
 """
 
 from __future__ import annotations

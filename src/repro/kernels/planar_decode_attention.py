@@ -4,7 +4,8 @@
 
 fp8 mode DMAs ONLY the hi planes (1 byte per cached element — half the
 HBM traffic) and treats them as float8_e5m2 truncated values; fp16 mode
-DMAs both planes and rejoins the exact f16 bits in VMEM. Online-softmax
+DMAs both planes and rejoins the exact f16 bits in VMEM. Both joins run
+in int32 lanes and yield f32 (`_join`). Online-softmax
 accumulation across cache blocks (innermost grid dim), masked by per-row
 valid lengths from SMEM.
 
@@ -20,13 +21,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.nestedfp import f16_bits_to_f32
+
 NEG_INF = -1e30
 DEFAULT_BLOCK_C = 512
 
 
-def _join(hi, lo):
-    bits = (hi.astype(jnp.uint16) << 8) | lo.astype(jnp.uint16)
-    return jax.lax.bitcast_convert_type(bits, jnp.float16)
+def _join(hi, lo=None):
+    """Byte planes -> exact f32 values, in int32 lanes (v5e has no 16-bit
+    vector integers or f16, so neither a u16 join nor an e5m2->f16 cast
+    lowers). lo=None reads the hi plane alone: an e5m2 value is the f16
+    whose low byte is zero."""
+    bits = hi.astype(jnp.int32) << 8
+    if lo is not None:
+        bits = bits | lo.astype(jnp.int32)
+    return f16_bits_to_f32(bits)
 
 
 def _kernel_fp16(q_ref, khi_ref, klo_ref, vhi_ref, vlo_ref, lens_ref,
@@ -43,9 +52,7 @@ def _kernel_fp16(q_ref, khi_ref, klo_ref, vhi_ref, vlo_ref, lens_ref,
 def _kernel_fp8(q_ref, khi_ref, vhi_ref, lens_ref,
                 o_ref, m_ref, l_ref, acc_ref, *, n_blocks, block_c,
                 window=None, win_ref=None):
-    k = jax.lax.bitcast_convert_type(khi_ref[0, 0], jnp.float8_e5m2)
-    v = jax.lax.bitcast_convert_type(vhi_ref[0, 0], jnp.float8_e5m2)
-    _attend(q_ref, k.astype(jnp.float16), v.astype(jnp.float16),
+    _attend(q_ref, _join(khi_ref[0, 0]), _join(vhi_ref[0, 0]),
             lens_ref, o_ref, m_ref, l_ref, acc_ref,
             n_blocks=n_blocks, block_c=block_c, window=window,
             win_ref=win_ref)

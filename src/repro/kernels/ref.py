@@ -18,10 +18,13 @@ def matmul_f16_ref(x: jax.Array, w: jax.Array,
     """Plain f16 GEMM oracle: (M,K) @ (K,N) -> (M,N).
 
     acc_dtype=bf16 is the serving fast-accum mode (Z4): partial sums cross
-    shards in bf16, halving TP all-reduce bytes."""
+    shards in bf16, halving TP all-reduce bytes. HIGHEST precision keeps
+    the f16 operands exact on the TPU, whose default f32-class dot rounds
+    them to bf16 (CPU results are unchanged)."""
     return jax.lax.dot_general(
         x.astype(jnp.float16), w.astype(jnp.float16),
-        (((1,), (0,)), ((), ())), preferred_element_type=acc_dtype)
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=acc_dtype)
 
 
 def nestedfp16_matmul_ref(x: jax.Array, upper: jax.Array,

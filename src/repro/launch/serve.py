@@ -39,10 +39,12 @@ def main() -> int:
 
     from repro.configs import get_arch
     from repro.core.policy import DualPrecisionController, SLOConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.models.convert import serving_memory_bytes, to_serving
     from repro.serving.engine import Engine, Request
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -55,8 +55,8 @@ class Runtime:
     # is opaque to the partitioner — with a mesh, the paged-decode
     # kernel runs under shard_map on per-shard head slices (KV heads
     # divisible by the model axis) and falls back to the ref gather
-    # path otherwise. None = single-device serving, byte-for-byte
-    # today's behavior.
+    # path otherwise, and the Pallas GEMMs run under shard_map on each
+    # chip's weight slice (core.linear). None = single-device serving.
 
     @property
     def serving(self) -> bool:
@@ -105,7 +105,7 @@ def apply_linear(rt: Runtime, p, x: jax.Array) -> jax.Array:
         mode = "fp8" if rt.mode == "fp8" else "fp16"
         return nested_linear(p, x, mode=mode, backend=rt.backend,
                              out_dtype=rt.dtype, fast_accum=rt.fast_accum,
-                             act_quant=rt.act_quant)
+                             act_quant=rt.act_quant, mesh=rt.mesh)
     y = jax.lax.dot_general(
         x.astype(rt.dtype), p["w"].astype(rt.dtype),
         (((x.ndim - 1,), (0,)), ((), ())),
@@ -430,7 +430,6 @@ def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
                 fp8 = rt.mode == "fp8"
                 interp = jax.default_backend() != "tpu"
                 if msz > 1:
-                    from jax.experimental.shard_map import shard_map
                     # window placeholder must be concrete for shard_map
                     # (0 = global; arithmetic-identical to None)
                     wa0 = wa if wa is not None \
@@ -441,13 +440,13 @@ def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
                             qq, kh, kl, vh, vl, tb, ln, fp8=fp8,
                             window_arr=w, interpret=interp)
                     pool = P(None, None, "model", None)
-                    o = shard_map(
+                    o = jax.shard_map(
                         _local, mesh=rt.mesh,
                         in_specs=(P(None, "model", None), pool, pool,
                                   pool, pool, P(None, None), P(None),
                                   P(None)),
                         out_specs=P(None, "model", None),
-                        check_rep=False)(
+                        check_vma=False)(
                         q[:, 0], new_cache["k_hi"], new_cache["k_lo"],
                         new_cache["v_hi"], new_cache["v_lo"], tables,
                         _as_lens(kv_len, b), wa0)[:, None]

@@ -189,7 +189,7 @@ _PENDING = -1
 class Engine:
     def __init__(self, cfg: ArchConfig, serving_params, *, n_slots: int,
                  capacity: int, controller: DualPrecisionController | None = None,
-                 forced_mode: str | None = None, backend: str = "ref",
+                 forced_mode: str | None = None, backend: str | None = None,
                  attn_backend: str = "ref",
                  kv_planar: bool = False,
                  clock: Callable[[], float] = time.monotonic,
@@ -303,6 +303,9 @@ class Engine:
         self.inject_stall_ms = 0.0
         self.last_stall_ms = 0.0
         self.last_mode: str | None = None
+        # backend=None lets the platform pick the GEMMs
+        # (ops.default_backend): the NestedFP Pallas kernels on TPU, the
+        # jnp oracle on CPU.
         # attn_backend="pallas" serves planar GQA decode through the
         # block-table scalar-prefetch kernel (layers.attention "paged");
         # anything it cannot serve falls back to the ref gather path.

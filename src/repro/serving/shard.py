@@ -34,10 +34,13 @@ single-device `model.paged_step` it wraps.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.linear import NestedLinearParams
 from repro.models import model as M
 
 
@@ -56,17 +59,37 @@ def put_replicated(mesh, a):
     return jax.device_put(a, replicated(mesh))
 
 
-def shard_serving_params(params, cfg, mesh):
-    """Commit a `to_serving` parameter tree onto the mesh via the
-    training-path resolver (`param_spec` sees the same dict keys —
-    wq/wk/... — through NestedLinearParams/NestedTensor pytree nodes,
-    and byte planes have the same shapes as the f16 weights they
-    encode)."""
+def serving_param_shardings(params, cfg, mesh):
+    """NamedShardings for a `to_serving` parameter tree (arrays or
+    ShapeDtypeStructs) via the training-path resolver (`param_spec` sees
+    the same dict keys — wq/wk/... — through NestedLinearParams/
+    NestedTensor pytree nodes, and byte planes have the same shapes as
+    the f16 weights they encode)."""
     from repro.launch import sharding as SH
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-    return jax.device_put(
-        params, SH.tree_shardings(shapes, mesh, SH.param_spec, cfg))
+    return SH.tree_shardings(shapes, mesh, SH.param_spec, cfg)
+
+
+def shard_serving_params(params, cfg, mesh):
+    """Commit a `to_serving` parameter tree onto the mesh, and record each
+    nested linear's (K, N) placement in its static `spec` (the last two
+    dims of its weight's PartitionSpec; stacked layers lead with the layer
+    axis), so `core.linear` runs the Pallas GEMM under shard_map on each
+    chip's slice."""
+    shardings = serving_param_shardings(params, cfg, mesh)
+
+    def tag(p, sh):
+        if not isinstance(p, NestedLinearParams):
+            return p
+        w = p.weight.raw if p.weight.is_exception else p.weight.upper
+        w_sh = sh.weight.raw if p.weight.is_exception else sh.weight.upper
+        spec = tuple(w_sh.spec) + (None,) * (w.ndim - len(w_sh.spec))
+        spec = spec[-2:]
+        return dataclasses.replace(
+            p, spec=spec if "model" in spec else None)
+    return jax.tree.map(tag, jax.device_put(params, shardings), shardings,
+                        is_leaf=lambda n: isinstance(n, NestedLinearParams))
 
 
 def sharded_paged_step(mesh, rt, params, cfg, tokens, caches, block_tables,

@@ -14,7 +14,7 @@ from repro.core import nestedfp as nf
 from repro.core import quant
 from repro.kernels import ops, ref
 from repro.kernels.f16_matmul import f16_matmul
-from repro.kernels.nestedfp16_matmul import nestedfp16_matmul, _reconstruct_f16
+from repro.kernels.nestedfp16_matmul import nestedfp16_matmul, _reconstruct_f32
 from repro.kernels.nestedfp8_matmul import nestedfp8_matmul, nestedfp8_matmul_fused_quant
 
 RNG = np.random.RandomState(42)
@@ -35,8 +35,8 @@ class TestReconstructInKernelHelper:
         w = jnp.asarray(RNG.uniform(-1.75, 1.75, (64, 64)).astype(np.float16))
         u, l = nf.encode(w)
         np.testing.assert_array_equal(
-            np.asarray(_reconstruct_f16(u, l)).view(np.uint16),
-            np.asarray(w).view(np.uint16))
+            np.asarray(_reconstruct_f32(u, l)).view(np.uint32),
+            np.asarray(w).astype(np.float32).view(np.uint32))
 
 
 class TestNestedFP16Kernel:
@@ -161,6 +161,36 @@ class TestRefBackendDispatch:
                                   block=(64, 128, 128))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-4)
+
+    def test_platform_picks_the_backend(self):
+        want = "pallas" if jax.default_backend() == "tpu" else "ref"
+        assert ops.default_backend() == want
+        x, w = _mk(8, 256, 128)
+        u, l = nf.encode(w)
+        np.testing.assert_array_equal(
+            np.asarray(ops.matmul_nested_f16(x, u, l)),
+            np.asarray(ops.matmul_nested_f16(x, u, l, backend=want)))
+
+    def test_unknown_backend_and_bad_shape_raise(self):
+        x, w = _mk(8, 256, 128)
+        u, l = nf.encode(w)
+        with pytest.raises(ValueError, match="unknown GEMM backend"):
+            ops.matmul_nested_f16(x, u, l, backend="cuda")
+        with pytest.raises(ValueError, match="contraction mismatch"):
+            ops.matmul_nested_fp8(x[:, :128], u, jnp.float32(1.0),
+                                  backend="ref")
+
+    def test_decode_batch_uses_a_small_m_block(self):
+        assert ops._fit_block((128, 128, 256), 8) == (8, 128, 256)
+        assert ops._fit_block((128, 128, 256), 13) == (16, 128, 256)
+        assert ops._fit_block((128, 128, 256), 300) == (128, 128, 256)
+        x, w = _mk(5, 256, 128)
+        u, l = nf.encode(w)
+        np.testing.assert_allclose(
+            np.asarray(ops.matmul_nested_f16(x, u, l,
+                                             backend="pallas_interpret")),
+            np.asarray(ref.nestedfp16_matmul_ref(x, u, l)),
+            rtol=1e-5, atol=1e-4)
 
     def test_exception_layer_plain_f16(self):
         x, w = _mk(64, 128, 64, wmax=3.0)   # not applicable

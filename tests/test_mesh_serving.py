@@ -189,6 +189,25 @@ class TestMeshParity:
         got, _ = _serve(cfg, sp, mesh, req(), **kw)
         assert got == ref
 
+    def test_pallas_gemms_shard_map_match_single_device(self, tiny, mesh):
+        """Pallas GEMM backend under the mesh: each nested linear runs
+        inside shard_map on its (K, N) slice — column-parallel q/k/v/gate/
+        up, row-parallel o/down with an all-reduce — and greedy tokens
+        match the single-device kernel run in both modes."""
+        cfg, sp = tiny
+        req = lambda: [Request(f"g{i}", list(p), max_new=3)
+                       for i, p in enumerate(PROMPTS[:2])]
+        for mode in ("fp16", "fp8"):
+            kw = dict(n_slots=2, capacity=64, forced_mode=mode,
+                      backend="pallas_interpret", prefix_cache=False)
+            ref, _ = _serve(cfg, sp, None, req(), **kw)
+            got, eng = _serve(cfg, sp, mesh, req(), **kw)
+            layer = eng.params["layers"]
+            assert layer["attn"]["wq"].spec == (None, "model")
+            assert layer["attn"]["wo"].spec == ("model", None)
+            assert layer["mlp"]["down"].spec == ("model", None)
+            assert got == ref, mode
+
     def test_mla_latent_replication_bit_exact(self, mesh):
         """MLA descriptor: latent planes replicate (no head axis), the
         absorbed attention shards over q heads — outputs exact."""

@@ -80,6 +80,19 @@ class TestExhaustive:
         assert np.all((u.astype(np.int32) & 0x7F) - (l.astype(np.int32) >> 7) >= 0)
 
 
+class TestF16BitsToF32:
+    def test_every_f16_pattern_exact(self):
+        """The int32-lane f16 -> f32 rebuild the v5e kernels use, over all
+        65536 bit patterns: normals, subnormals, signed zeros, inf, NaN."""
+        bits = np.arange(1 << 16, dtype=np.uint32)
+        got = np.asarray(nf.f16_bits_to_f32(jnp.asarray(bits, jnp.int32)))
+        want = bits.astype(np.uint16).view(np.float16).astype(np.float32)
+        nan = np.isnan(want)
+        assert np.array_equal(np.isnan(got), nan)
+        np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                      want[~nan].view(np.uint32))
+
+
 class TestProperty:
     @settings(max_examples=200, deadline=None)
     @given(st.lists(st.floats(-1.75, 1.75, width=16, allow_nan=False),

@@ -84,6 +84,19 @@ class TestEngine:
         b = _greedy_reference(cfg, sparams, prompt, 4, mode="fp8")
         assert len(a) == len(b) == 4  # same shape; tokens may differ slightly
 
+    def test_gemm_backend_defaults_to_the_platform(self, tiny):
+        """Engine() without backend= runs the platform's GEMMs: the jnp
+        oracle on CPU (Pallas on TPU, covered by chip_smoke.py)."""
+        from repro.kernels import ops
+        cfg, sp = tiny
+        outs = []
+        for kw in ({}, {"backend": ops.default_backend()}):
+            eng = Engine(cfg, sp, n_slots=2, capacity=32, forced_mode="fp16",
+                         **kw)
+            eng.submit(Request("d", list(range(3, 12)), max_new=4))
+            outs.append(eng.run()[0].output)
+        assert outs[0] == outs[1]
+
     def test_stop_tokens_retire_early(self, tiny):
         """EOS emission retires the request mid-stream: output is the
         greedy prefix through the stop token, and the slot frees for
@@ -275,3 +288,35 @@ class TestPlanarEngine:
         eng.submit(Request("r0", list(range(5, 13)), max_new=4))
         fin = eng.run()
         assert len(fin) == 1 and len(fin[0].output) == 4
+
+
+class TestServingParams:
+    def test_on_device_build_matches_host_conversion(self, tiny):
+        """`init_serving_params` (one jitted init + encode, shardable) has
+        `to_serving`'s tree and shapes, and its FP16 weights are the f16
+        rounding of the same init (within the last f32 bit of the init,
+        i.e. at most one f16 ulp)."""
+        from repro.core import nestedfp as nf
+        from repro.models.convert import (init_serving_params,
+                                          serving_param_shapes)
+        cfg, sp = tiny
+        built = init_serving_params(jax.random.PRNGKey(0), cfg)
+        assert jax.tree.structure(built) == jax.tree.structure(sp)
+        assert jax.tree.structure(serving_param_shapes(cfg)) == \
+            jax.tree.structure(sp)
+        for a, b in zip(jax.tree.leaves(built), jax.tree.leaves(sp)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+        w = sp["layers"]["mlp"]["gate"].weight
+        v = built["layers"]["mlp"]["gate"].weight
+        ulps = np.abs(
+            np.asarray(nf.decode(w.upper, w.lower)).view(np.int16).astype(int)
+            - np.asarray(nf.decode(v.upper, v.lower)).view(np.int16))
+        assert ulps.max() <= 1
+
+    def test_on_device_build_refuses_exception_layers(self):
+        import dataclasses
+        from repro.models.convert import init_serving_params
+        cfg = dataclasses.replace(ARCHS["qwen1.5-0.5b"].reduced(),
+                                  d_model=1)     # init scale 1/sqrt(1): |w| > 1.75
+        with pytest.raises(ValueError, match="1.75"):
+            init_serving_params(jax.random.PRNGKey(0), cfg)
