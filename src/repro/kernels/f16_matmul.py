@@ -64,5 +64,5 @@ def f16_matmul(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="f16_matmul",
     )(x.astype(jnp.float16).astype(jnp.float32), hi, lo)

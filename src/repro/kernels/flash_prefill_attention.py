@@ -92,6 +92,6 @@ def flash_prefill_attention(q, k, v, *, block=DEFAULT_BLOCK,
         scratch_shapes=[pltpu.VMEM((g * bq, 1), jnp.float32),
                         pltpu.VMEM((g * bq, 1), jnp.float32),
                         pltpu.VMEM((g * bq, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_prefill_attention",
     )(qg, kt, vt)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)

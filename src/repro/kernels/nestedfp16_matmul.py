@@ -108,5 +108,5 @@ def nestedfp16_matmul(x: jax.Array, upper: jax.Array, lower: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="nestedfp16_matmul",
     )(x.astype(jnp.float16).astype(jnp.float32), upper, lower)

@@ -77,7 +77,7 @@ def nestedfp8_matmul(x_q: jax.Array, upper: jax.Array, x_scale: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="nestedfp8_matmul",
     )(x_q, upper, x_scale.reshape(1).astype(jnp.float32))
 
 
@@ -132,5 +132,5 @@ def nestedfp8_matmul_fused_quant(x: jax.Array, upper: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="nestedfp8_matmul_fused_quant",
     )(x, upper, amax.reshape(1).astype(jnp.float32))

@@ -52,5 +52,5 @@ def nestedfp_encode(w: jax.Array, *, block: tuple[int, int] = DEFAULT_BLOCK,
         out_specs=(spec, spec),
         out_shape=(jax.ShapeDtypeStruct((m, n), jnp.uint8),
                    jax.ShapeDtypeStruct((m, n), jnp.uint8)),
-        interpret=interpret,
+        interpret=interpret, name="nestedfp_encode",
     )(w.astype(jnp.float16))

@@ -198,7 +198,8 @@ def paged_planar_decode_attention(q, k_hi, k_lo, v_hi, v_lo, tables, lens, *,
         out_specs=out_spec,
         scratch_shapes=scratch)
     out = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                         interpret=interpret)(
+                         interpret=interpret,
+                         name="paged_planar_decode_attention")(
         tables.astype(jnp.int32), lens.astype(jnp.int32),
         jnp.asarray(window_arr, jnp.int32).reshape(1), qg, *ins)
     return out.reshape(bsz, h, d)
@@ -243,6 +244,7 @@ def planar_decode_attention(q, k_hi, k_lo, v_hi, v_lo, lens, *,
                       pl.BlockSpec(memory_space=pltpu.SMEM)],
             out_specs=out_spec, out_shape=out_shape,
             scratch_shapes=scratch, interpret=interpret,
+            name="planar_decode_attention",
         )(qg, planes[0], planes[2], lens.astype(jnp.int32))
     else:
         out = pl.pallas_call(
@@ -253,5 +255,6 @@ def planar_decode_attention(q, k_hi, k_lo, v_hi, v_lo, lens, *,
                       pl.BlockSpec(memory_space=pltpu.SMEM)],
             out_specs=out_spec, out_shape=out_shape,
             scratch_shapes=scratch, interpret=interpret,
+            name="planar_decode_attention",
         )(qg, *planes, lens.astype(jnp.int32))
     return out.reshape(bsz, h, d)

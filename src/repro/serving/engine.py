@@ -116,6 +116,14 @@ or decoding:
 `stats` counts `prefill_dispatches`/`decode_dispatches`/
 `aux_dispatches` and `h2d_bytes`; `benchmarks/bench_kernel_overhead.py`
 turns them into the `engine_dispatch/*` rows the CI smoke asserts.
+
+Profiler spans: every step is a `jax.profiler` step span `engine.step`
+holding one span per phase that has work — `engine.restore`,
+`engine.schedule`, `engine.prefill` (`mode`, `rows`, `tokens`),
+`engine.decode` (`mode`, `rows`), `engine.sync` (the device->host pulls
+alone), `engine.finalize`, and `engine.spill` (`blocks`) wherever a spill
+capture runs — so a trace of the serving process puts each gap in the
+device's timeline down to the phase the host was in.
 """
 
 from __future__ import annotations
@@ -156,8 +164,13 @@ class Request:
     # stop token itself is kept in `output`, EOS-style); an accepted
     # speculative run is cut at the first stop token mid-run
     stop_tokens: tuple[int, ...] = ()
-    # filled by the engine:
+    # filled by the engine, on its `clock`: `submitted_s` when `submit`
+    # accepts it, `admitted_s` at its first admission (a re-admission
+    # after preemption keeps it), and each token's time once the step's
+    # sync has brought the token to the host
     output: list[int] = dataclasses.field(default_factory=list)
+    submitted_s: float | None = None
+    admitted_s: float | None = None
     first_token_s: float | None = None
     finished_s: float | None = None
     token_times: list[float] = dataclasses.field(default_factory=list)
@@ -179,6 +192,14 @@ def _bucket(n: int, minimum: int = 16) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _span(name: str, on: bool = True, **args):
+    """A profiler span over one phase of a step (`jax.profiler`, on the
+    clock of the device ops); none for a phase with no work. With no
+    profiler session it costs about a microsecond."""
+    return jax.profiler.TraceAnnotation(name, **args) if on \
+        else contextlib.nullcontext()
 
 
 # placeholder for a token whose value still lives on device; patched by
@@ -504,6 +525,8 @@ class Engine:
                 f"request {req.request_id}: needs more KV blocks than a "
                 f"whole group pool holds ({bm.n_blocks}) — the pool can "
                 f"never cover it")
+        if req.submitted_s is None:          # a failover resubmission
+            req.submitted_s = self.clock()   # keeps its first stamp
         self.queue.append(req)
 
     def drain_requests(self) -> list[Request]:
@@ -531,10 +554,9 @@ class Engine:
         for req in out:
             while req.output and req.output[-1] == _PENDING:
                 req.output.pop()
-                if req.token_times:
-                    req.token_times.pop()
                 if req.modes:
                     req.modes.pop()
+            del req.token_times[len(req.output):]
             if not req.output:
                 req.first_token_s = None     # the dropped placeholder was
                                              # the "first token"
@@ -641,26 +663,27 @@ class Engine:
         repeating the last id — idempotent), then a single batched d2h
         pull per group. Used by `_flush_spills` (eviction/preemption
         spills) and `save_prefix_store` (non-evicting index mirror)."""
-        bm = self.blocks
-        by_g: dict[int, list[tuple[int, int]]] = {}
-        for g, b, h in jobs:
-            by_g.setdefault(g, []).append((b, h))
-        for g, items in sorted(by_g.items()):
-            kb = _bucket(len(items), 1)
-            ids = np.full(kb, items[-1][0], np.int32)
-            for i, (b, _h) in enumerate(items):
-                ids[i] = b
-            out = self._spill_gather[g](self.caches, self._tier_dev(ids))
-            # nfp: ignore[NFP001] tiered-KV spill capture: batched d2h of evicted cold blocks, an aux transfer that never sits on the step's argmax sync
-            planes = jax.device_get(out)
-            for i, (_b, h) in enumerate(items):
-                entry = {p: np.ascontiguousarray(a[:, i])
-                         for p, a in planes.items()}
-                bm.store_spill(g, h, entry)
-                self.stats["spilled_blocks"] += 1
-                self.stats["spilled_bytes"] += sum(
-                    a.nbytes for a in entry.values())
-            self.stats["aux_dispatches"] += 1
+        with _span("engine.spill", bool(jobs), blocks=len(jobs)):
+            bm = self.blocks
+            by_g: dict[int, list[tuple[int, int]]] = {}
+            for g, b, h in jobs:
+                by_g.setdefault(g, []).append((b, h))
+            for g, items in sorted(by_g.items()):
+                kb = _bucket(len(items), 1)
+                ids = np.full(kb, items[-1][0], np.int32)
+                for i, (b, _h) in enumerate(items):
+                    ids[i] = b
+                out = self._spill_gather[g](self.caches, self._tier_dev(ids))
+                # nfp: ignore[NFP001] tiered-KV spill capture: batched d2h of evicted cold blocks, an aux transfer that never sits on the step's argmax sync
+                planes = jax.device_get(out)
+                for i, (_b, h) in enumerate(items):
+                    entry = {p: np.ascontiguousarray(a[:, i])
+                             for p, a in planes.items()}
+                    bm.store_spill(g, h, entry)
+                    self.stats["spilled_blocks"] += 1
+                    self.stats["spilled_bytes"] += sum(
+                        a.nbytes for a in entry.values())
+                self.stats["aux_dispatches"] += 1
 
     def _flush_spills(self) -> None:
         """Capture every queued evicted-block spill to the host tier.
@@ -910,7 +933,9 @@ class Engine:
             # the ambient mesh lets shard_hint constraints inside the
             # model stack (mla absorbed-q pinning et al.) take effect;
             # all committed-operand partitioning works without it
-            self._step_inner()
+            with jax.profiler.StepTraceAnnotation("engine.step",
+                                                  step_num=self.iteration):
+                self._step_inner()
 
     def _step_inner(self) -> None:
         if self.fault_hook is not None:
@@ -922,23 +947,32 @@ class Engine:
         t0 = self.clock()
         # land queued host-tier restores first (SLO-bounded): rows whose
         # blocks finish restoring here become schedulable this very step
-        self._sweep_corrupt_lo()
-        self._drain_restores()
-        plan = self._plan_chunks()
-        mode = self._mode(len(self.active),
-                          sum(take for _, _, take in plan),
-                          free_block_frac=self.blocks.free_block_frac())
-        # planar pools restore hi planes eagerly, lo lazily: the first
-        # FP16-mode step joins hi+lo, so deferred lo bytes land NOW
-        self._ensure_lo(mode)
+        bm = self.blocks
+        with _span("engine.restore", self._host_tier and bool(
+                bm.restore_jobs or (self._lo_planes and bm._lo_pending)),
+                   blocks=len(bm.restore_jobs)):
+            self._sweep_corrupt_lo()
+            self._drain_restores()
+        with _span("engine.schedule"):
+            plan = self._plan_chunks()
+            tokens = sum(take for _, _, take in plan)
+            mode = self._mode(len(self.active), tokens,
+                              free_block_frac=bm.free_block_frac())
+            # planar pools restore hi planes eagerly, lo lazily: the first
+            # FP16-mode step joins hi+lo, so deferred lo bytes land NOW
+            self._ensure_lo(mode)
         # pending: (req, output index, device ids, row, slot) patched —
         # and EOS-checked — at the end-of-step sync; fresh: (slot,
         # device ids, row) prefills that completed this step and decode
         # below with a device-held token
         pending: list[tuple[Request, int, Any, int, int]] = []
         fresh: list[tuple[int, Any, int]] = []
-        chunk_ids = self._run_chunks(mode, plan, pending, fresh)
-        decode_ids, drafts = self._decode_paged(mode, chunk_ids, fresh)
+        with _span("engine.prefill", bool(plan), mode=mode, rows=len(plan),
+                   tokens=tokens):
+            chunk_ids = self._run_chunks(mode, plan, pending, fresh)
+        with _span("engine.decode", bool(self.active), mode=mode,
+                   rows=len(self.active)):
+            decode_ids, drafts = self._decode_paged(mode, chunk_ids, fresh)
         self._finalize_step(mode, pending, decode_ids, drafts)
         self._sample_peak()
         # wall time of this step feeds the controller's p90 tracker on the
@@ -997,6 +1031,8 @@ class Engine:
             if idx is None:
                 break
             self.queue.popleft()
+            if req.admitted_s is None:
+                req.admitted_s = self.clock()
             if self.slot_state is not None:
                 # slot-resident state side: claim the same slot index and
                 # zero its recurrent state (recompute after preemption
@@ -1258,15 +1294,11 @@ class Engine:
         req = st.req
         req.output.append(_PENDING)
         pending.append((req, len(req.output) - 1, ids, row, idx))
-        now = self.clock()
-        if req.first_token_s is None:
-            req.first_token_s = now
-        req.token_times.append(now)
         req.modes.append(mode)
         self.lens[idx] = len(st.seq_tokens)
         self.active[idx] = req
         del self.prefilling[idx]
-        self._maybe_retire(idx, now)
+        self._maybe_retire(idx, self.clock())
         if idx in self.active:
             fresh.append((idx, ids, row))
 
@@ -1457,68 +1489,80 @@ class Engine:
         newly-filled blocks (a multi-token emission can fill several)
         and advances the length. The LAST emitted token is never in the
         cache — it is the next step's input, exactly as in plain
-        decode."""
-        nxt = None if decode_ids is None else np.asarray(decode_ids)
-        now = self.clock()
-        for req, pos, ids, row, idx in pending:
-            req.output[pos] = int(np.asarray(ids)[row])
-            if req.output[pos] in req.stop_tokens \
-                    and self.active.get(idx) is req:
-                self._retire(idx, now)
-        if nxt is None:
-            return
-        if drafts is None:
-            for idx, req in list(self.active.items()):
-                self.lens[idx] += 1
-                n = int(self.lens[idx])
-                if n % self.block_size == 0:
-                    # tail block just filled: register it in the prefix
-                    # index (generated content is reusable too — replays
-                    # after preemption and shared multi-turn history)
-                    self.blocks.commit(idx, n,
-                                       (req.tokens + req.output)[:n])
-                else:
-                    self.blocks.set_length(idx, n)
-                req.output.append(int(nxt[idx]))
+        decode.
+
+        Token times are taken after the pull, so each is a time at which
+        the token exists on the host: a completed prefill's first token
+        (and `first_token_s`) included."""
+        with _span("engine.sync"):
+            nxt = None if decode_ids is None else np.asarray(decode_ids)
+            firsts = [int(np.asarray(ids)[row])
+                      for _, _, ids, row, _ in pending]
+        with _span("engine.finalize"):
+            now = self.clock()
+            for (req, pos, _, _, idx), tok in zip(pending, firsts):
+                req.output[pos] = tok
                 req.token_times.append(now)
-                req.modes.append(mode)
+                if req.first_token_s is None:
+                    req.first_token_s = now
+                if req.finished_s is not None:   # retired before the sync
+                    req.finished_s = now
+                if tok in req.stop_tokens and self.active.get(idx) is req:
+                    self._retire(idx, now)
+            if nxt is None:
+                return
+            if drafts is None:
+                for idx, req in list(self.active.items()):
+                    self.lens[idx] += 1
+                    n = int(self.lens[idx])
+                    if n % self.block_size == 0:
+                        # tail block just filled: register it in the prefix
+                        # index (generated content is reusable too — replays
+                        # after preemption and shared multi-turn history)
+                        self.blocks.commit(idx, n,
+                                           (req.tokens + req.output)[:n])
+                    else:
+                        self.blocks.set_length(idx, n)
+                    req.output.append(int(nxt[idx]))
+                    req.token_times.append(now)
+                    req.modes.append(mode)
+                    self.stats["decode_rows"] += 1
+                    self.stats["decode_tokens"] += 1
+                    self._maybe_retire(idx, now)
+                if self._spec is not None:
+                    self._last_spec = (0, 0)
+                return
+            drafted_total = accepted_total = 0
+            for idx, req in list(self.active.items()):
+                d = drafts.get(idx, ())
+                n_acc = int(nxt[idx, -1]) if d else 0
+                out = [int(t) for t in nxt[idx, :n_acc + 1]]
+                drafted_total += len(d)
+                accepted_total += n_acc
+                # EOS stops an accepted run MID-RUN: everything after the
+                # first stop token is discarded (never emitted), and the
+                # output budget bounds the emission the same way
+                for j, t in enumerate(out):
+                    if t in req.stop_tokens:
+                        out = out[:j + 1]
+                        break
+                out = out[:req.max_new - len(req.output)]
+                new_n = int(self.lens[idx]) + len(out)
+                # rollback: drop the blocks covering rejected positions
+                # (their writes landed in COW-exclusive unregistered blocks;
+                # what survives inside the kept tail block beyond new_n is
+                # masked by kv_len and overwritten before it can be read)
+                self.blocks.truncate(idx, new_n)
+                self.blocks.commit(idx, new_n,
+                                   (req.tokens + req.output + out)[:new_n])
+                self.lens[idx] = new_n
+                req.output.extend(out)
+                req.token_times.extend([now] * len(out))
+                req.modes.extend([mode] * len(out))
                 self.stats["decode_rows"] += 1
-                self.stats["decode_tokens"] += 1
+                self.stats["decode_tokens"] += len(out)
                 self._maybe_retire(idx, now)
-            if self._spec is not None:
-                self._last_spec = (0, 0)
-            return
-        drafted_total = accepted_total = 0
-        for idx, req in list(self.active.items()):
-            d = drafts.get(idx, ())
-            n_acc = int(nxt[idx, -1]) if d else 0
-            out = [int(t) for t in nxt[idx, :n_acc + 1]]
-            drafted_total += len(d)
-            accepted_total += n_acc
-            # EOS stops an accepted run MID-RUN: everything after the
-            # first stop token is discarded (never emitted), and the
-            # output budget bounds the emission the same way
-            for j, t in enumerate(out):
-                if t in req.stop_tokens:
-                    out = out[:j + 1]
-                    break
-            out = out[:req.max_new - len(req.output)]
-            new_n = int(self.lens[idx]) + len(out)
-            # rollback: drop the blocks covering rejected positions
-            # (their writes landed in COW-exclusive unregistered blocks;
-            # what survives inside the kept tail block beyond new_n is
-            # masked by kv_len and overwritten before it can be read)
-            self.blocks.truncate(idx, new_n)
-            self.blocks.commit(idx, new_n,
-                               (req.tokens + req.output + out)[:new_n])
-            self.lens[idx] = new_n
-            req.output.extend(out)
-            req.token_times.extend([now] * len(out))
-            req.modes.extend([mode] * len(out))
-            self.stats["decode_rows"] += 1
-            self.stats["decode_tokens"] += len(out)
-            self._maybe_retire(idx, now)
-        self.stats["spec_drafted"] += drafted_total
-        self.stats["spec_accepted"] += accepted_total
-        self._last_spec = (drafted_total, accepted_total)
+            self.stats["spec_drafted"] += drafted_total
+            self.stats["spec_accepted"] += accepted_total
+            self._last_spec = (drafted_total, accepted_total)
 

@@ -236,10 +236,9 @@ class Router:
         recovered from the router's registry instead)."""
         while req.output and req.output[-1] == _PENDING:
             req.output.pop()
-            if req.token_times:
-                req.token_times.pop()
             if req.modes:
                 req.modes.pop()
+        del req.token_times[len(req.output):]
         if not req.output:
             req.first_token_s = None
         return req

@@ -104,12 +104,16 @@ class TestEngine:
         cfg, sparams = tiny
         prompt = list(range(5, 13))
         ref = _greedy_reference(cfg, sparams, prompt, 6)
+        # the stop token is the first reference token that does not occur
+        # earlier in the stream, so generation must run past index 0
+        stop = next(i for i, t in enumerate(ref) if i and t not in ref[:i])
         eng = Engine(cfg, sparams, n_slots=1, capacity=64,
                      forced_mode="fp16")
-        eng.submit(Request("r0", prompt, max_new=6, stop_tokens=(ref[2],)))
+        eng.submit(Request("r0", prompt, max_new=6,
+                           stop_tokens=(ref[stop],)))
         eng.submit(Request("r1", prompt, max_new=6))
         fin = {r.request_id: r.output for r in eng.run()}
-        assert fin["r0"] == ref[:3], "did not stop AT the stop token"
+        assert fin["r0"] == ref[:stop + 1], "did not stop AT the stop token"
         assert fin["r1"] == ref, "slot not recycled after EOS retirement"
 
     def test_controller_switches_under_load(self, tiny):
