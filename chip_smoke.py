@@ -14,12 +14,13 @@ One chip: qwen1.5-0.5b at its full published width, weights random from
   * logits: first-step logits of an 8 x 128-token prefill through the
     Pallas GEMMs against the jnp oracle on the same chip, in FP16 and
     FP8 mode (LOGIT_TOL);
-  * attention: the paged planar decode kernel against a jnp reference
-    at the model's head shapes, in both modes (ATTN_TOL);
+  * attention: the paged planar decode kernel in both modes, and the
+    plain-pool paged decode kernel, against a jnp reference at the
+    model's head shapes (ATTN_TOL);
   * serving: 8 requests (128-token prompts, 32 new tokens, 8 slots,
     capacity 512) in forced FP16, in forced FP8 (the same nested weight
-    bytes), and in FP16 over a byte-planar KV pool whose decode attention
-    runs in the Pallas kernel. Each pass AOT-compiles its decode and
+    bytes), and in FP16 over a byte-planar KV pool, decode attention in
+    the Pallas kernels throughout. Each pass AOT-compiles its decode and
     prefill step programs first, timing the compile and counting the
     `tpu_custom_call`s in them.
 
@@ -290,6 +291,40 @@ def token_agreement(a: dict, b: dict) -> float:
     return float(np.mean([x == y for k in a for x, y in zip(a[k], b[k])]))
 
 
+def check_paged_attention(cfg, seed: int) -> None:
+    """Plain-pool paged decode kernel vs a jnp reference over the same
+    pool (gather the blocks in logical order, masked softmax)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.paged_decode_attention import paged_decode_attention
+    h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    b, mb = SLOTS, CAPACITY // BLOCK
+    rng = np.random.RandomState(seed)
+    nb = b * mb + 1
+    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(nb, BLOCK, hkv * d)), jnp.float16)
+    v = jnp.asarray(rng.normal(size=(nb, BLOCK, hkv * d)), jnp.float16)
+    tables = jnp.asarray(1 + rng.permutation(b * mb).reshape(b, mb),
+                         jnp.int32)
+    lens = jnp.asarray(rng.randint(1, CAPACITY + 1, b), jnp.int32)
+    bits = lambda a: jax.lax.bitcast_convert_type(a, jnp.int16)  # noqa: E731
+    got = paged_decode_attention(q, bits(k), bits(v), tables, lens)
+    kk = k.astype(jnp.float32)[tables].reshape(b, mb * BLOCK, hkv, d)
+    vv = v.astype(jnp.float32)[tables].reshape(b, mb * BLOCK, hkv, d)
+    qg = q.reshape(b, hkv, h // hkv, d) * d ** -0.5
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bhgd,bkhd->bhgk", qg, kk)
+        s = jnp.where(jnp.arange(mb * BLOCK) < lens[:, None, None, None],
+                      s, -1e30)
+        want = jnp.einsum("bhgk,bkhd->bhgd", jax.nn.softmax(s, -1),
+                          vv).reshape(b, h, d)
+    got, want = np.asarray(got), np.asarray(want)
+    err = float(np.abs(got - want).max() / np.abs(want).max())
+    emit(phase="paged_attention", rel_err=err, tol=ATTN_TOL)
+    check(np.isfinite(got).all() and err <= ATTN_TOL,
+          f"paged attention: rel err {err} > {ATTN_TOL}")
+
+
 def serve(cfg, params, reqs, phase: str, mode: str, **engine_kw) -> dict:
     """Serve `reqs` through a fresh Engine forced to `mode`, after
     compiling its step programs (`compile_steps`); every request must
@@ -339,14 +374,14 @@ def one_chip(seed: int) -> None:
     for mode in ("fp16", "fp8"):
         check_logits(cfg, params, reqs, mode)
     check_planar_attention(cfg, seed)
+    check_paged_attention(cfg, seed)
     served = {}
     compile_total = 0.0
     for phase, mode, planar in (("serve_fp16", "fp16", False),
                                 ("serve_fp8", "fp8", False),
                                 ("serve_planar_pallas_attn", "fp16", True)):
         row = serve(cfg, params, make_requests(cfg, SLOTS, seed, "q"), phase,
-                    mode, kv_planar=planar,
-                    attn_backend="pallas" if planar else "ref")
+                    mode, kv_planar=planar, attn_backend="pallas")
         served[phase] = row.pop("outputs")
         compile_total += row["compile_s"]
         emit(**row)

@@ -41,29 +41,25 @@ one-dispatch engine steps
   into BENCH_results.json by `python -m benchmarks.run`.
 
 --attn-backend selection
-  ref     pure-jnp block-table gather attention (default; every family)
-  pallas  planar decode attention runs in the block-table
-          scalar-prefetch Pallas kernel (kernels/
-          planar_decode_attention.py): fp16 mode rejoins the NestedKV
-          byte planes in-kernel, fp8 mode DMAs ONLY the hi planes, and
-          gemma3 sliding windows ride a traced per-layer window operand.
-          Requires --kv-planar (GQA archs); anything the kernel cannot
-          serve (prefill chunks, MLA/hybrid, f16 caches) falls back to
-          the ref gather path. Interpret-mode (slow, exact) off-TPU.
+  (unset) the platform picks: pallas on a TPU, ref elsewhere
+  ref     pure-jnp block-table gather attention (every family)
+  pallas  single-token decode over a GQA pool runs in a block-table
+          scalar-prefetch Pallas kernel that reads only each row's live
+          blocks (kernels/paged_decode_attention.py; with --kv-planar,
+          kernels/planar_decode_attention.py, whose fp8 mode DMAs ONLY
+          the hi planes); gemma3 sliding windows ride a traced per-layer
+          window operand. Prefill chunks and MLA keep the ref gather
+          path. Interpret-mode (slow, exact) off-TPU.
 """)
 ap.add_argument("--arch", default="qwen3-8b", choices=sorted(ARCHS),
                 help="architecture (reduced variant); any decoder-only "
                      "family serves through the paged engine")
-ap.add_argument("--attn-backend", default="ref", choices=["ref", "pallas"],
-                help="paged decode attention backend (see epilog); "
-                     "pallas requires --kv-planar")
+ap.add_argument("--attn-backend", choices=["ref", "pallas"],
+                help="paged decode attention backend (see epilog)")
 ap.add_argument("--kv-planar", action="store_true",
                 help="store GQA KV as NestedKV byte planes (fp8 decode "
                      "reads half the cache bytes)")
 args = ap.parse_args()
-if args.attn_backend == "pallas" and not args.kv_planar:
-    ap.error("--attn-backend pallas serves the byte-planar NestedKV "
-             "cache; pass --kv-planar")
 
 cfg = ARCHS[args.arch].reduced()
 params = M.init_params(jax.random.PRNGKey(0), cfg)

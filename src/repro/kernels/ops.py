@@ -33,8 +33,8 @@ BACKENDS = ("pallas", "pallas_interpret", "ref")
 
 
 def default_backend() -> str:
-    """The platform's GEMM backend: the Pallas kernels on TPU, the jnp
-    oracle elsewhere."""
+    """The platform's kernel backend, for the GEMMs and for paged decode
+    attention: the Pallas kernels on TPU, the jnp oracle elsewhere."""
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 

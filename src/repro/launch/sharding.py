@@ -268,10 +268,11 @@ def paged_cache_spec(path, leaf, cfg: ArchConfig, mesh) -> P:
     """Serving-engine paged pools (model.init_paged_cache layout — no
     batch dim; sequences own block ids, not rows).
 
-    * "attn"/"shared" GQA planes (L, NB, BS, Hkv, Hd) — f16 k/v or the
-      four uint8 NestedKV byte planes — shard the KV-HEAD axis over
-      `model` when divisible; indivisible head counts (gemma3's 1 kv
-      head) replicate the pool, mirroring the K/V projection fallback in
+    * "attn"/"shared" GQA planes — k/v (L, NB, BS, Hkv*Hd) or the
+      four uint8 NestedKV byte planes (L, NB, BS, Hkv, Hd) — shard the
+      KV heads over `model` when divisible (the merged axis splits on
+      head boundaries); indivisible head counts (gemma3's 1 kv head)
+      replicate the pool, mirroring the K/V projection fallback in
       `param_spec` so pool and projection land on the same layout.
     * MLA latent planes (L, NB, BS, r): no head axis — replicate. The
       block axis CANNOT be sharded (the engine scatters at dynamic
@@ -287,6 +288,8 @@ def paged_cache_spec(path, leaf, cfg: ArchConfig, mesh) -> P:
     dims: list[Any] = [None] * len(shape)
     if any(k in keys for k in ("attn", "shared")):
         if len(shape) == 5 and _div(shape[3], m):
+            dims[3] = "model"
+        elif keys[-1] in ("k", "v") and _div(cfg.n_kv_heads, m):
             dims[3] = "model"
     elif "conv_x" in keys:
         if len(shape) == 4 and _div(shape[3], m):

@@ -44,11 +44,12 @@ class Runtime:
     # co-batched requests (and break spec-on/off bit-exactness).
     attn_backend: str | None = None
     # paged-decode attention backend: "pallas" routes single-token paged
-    # decode over byte-planar (NestedKV) GQA caches through the
-    # scalar-prefetch block-table kernel (interpret-mode off-TPU);
-    # None/"ref" keeps the pure-jnp gather path. Orthogonal to `backend`
-    # (the GEMM kernel selector) so pallas attention can pair with ref
-    # matmuls on CPU.
+    # decode over GQA pools through a scalar-prefetch block-table kernel
+    # (plain pools: kernels/paged_decode_attention.py; byte-planar
+    # NestedKV: kernels/planar_decode_attention.py; interpret mode
+    # off-TPU); None/"ref" keeps the pure-jnp gather path. Orthogonal to
+    # `backend` (the GEMM kernel selector) so pallas attention can pair
+    # with ref matmuls on CPU.
     mesh: Any = None
     # serving mesh (Engine(mesh=...)): the pure-jnp paths partition via
     # GSPMD from the committed weight/pool shardings, but a pallas_call
@@ -342,6 +343,55 @@ def _qkv(rt, p, cfg, x, positions):
     return q, k, v
 
 
+def _model_axis(mesh) -> int:
+    return mesh.shape["model"] \
+        if mesh is not None and "model" in mesh.axis_names else 1
+
+
+def paged_decode_kernel(rt: Runtime, n_kv_heads: int) -> bool:
+    """Whether single-query paged decode over a GQA pool (plain or
+    planar) runs a Pallas kernel under `rt`: attn_backend "pallas" and,
+    on a serving mesh, KV heads that divide its model axis (the kernel
+    runs under shard_map on per-shard head slices; otherwise the
+    GSPMD-partitionable gather serves). Prefill chunks and speculative
+    verification (C > 1) always take the gather."""
+    return rt.attn_backend == "pallas" \
+        and n_kv_heads % _model_axis(rt.mesh) == 0
+
+
+def _decode_through_kernel(rt, kernel, q, pools, pool_specs, phys_read,
+                           kv_len, window):
+    """One layer's C = 1 decode through a block-table kernel
+    `kernel(q, *pools, tables, lens, window_arr=, interpret=)`. The table
+    is recovered from phys_read (= table x BS + offset) by striding; the
+    scanned per-layer window rides as a traced (1,) operand so one
+    executable serves a mixed local/global stack. Interpret mode off-TPU.
+    q: (B, H, D) -> (B, H, D) f32."""
+    b = q.shape[0]
+    bs_tok = pools[0].shape[1]
+    tables = phys_read[:, ::bs_tok] // bs_tok                # (B, MB)
+    lens = _as_lens(kv_len, b)
+    wa = None if window is None \
+        else jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
+    interp = jax.default_backend() != "tpu"
+
+    def run(qq, *rest):
+        *pl_, tb, ln, w = rest
+        return kernel(qq, *pl_, tb, ln, window_arr=w, interpret=interp)
+    if _model_axis(rt.mesh) == 1:
+        return run(q, *pools, tables, lens, wa)
+    # KV heads over `model`; q heads follow since H = Hkv*G. The window
+    # placeholder must be concrete for shard_map (0 = global)
+    if wa is None:
+        wa = jnp.zeros((1,), jnp.int32)
+    return jax.shard_map(
+        run, mesh=rt.mesh,
+        in_specs=(P(None, "model", None), *pool_specs, P(None, None),
+                  P(None), P(None)),
+        out_specs=P(None, "model", None), check_vma=False)(
+        q, *pools, tables, lens, wa)
+
+
 def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
               phase: str, positions: jax.Array, window=None,
               cache: dict | None = None, kv_len=None, causal: bool = True,
@@ -354,7 +404,8 @@ def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
     and returns (out, cache).
 
     paged: (phys_write (B,C), phys_read (B,Cap), q_offset (B,)) flat
-    physical indices into the block pool (leaves shaped (NB, BS, Hkv, ·)).
+    physical indices into the block pool (plain leaves (NB, BS, Hkv*D)
+    of f16 bits as int16, byte-planar ones (NB, BS, Hkv, D)).
     The chunk's k/v are scattered at phys_write (pad/inactive columns
     point at the trash block), then keys are gathered back in logical
     order via phys_read — so chunked and monolithic prefill see
@@ -393,70 +444,21 @@ def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
                 fl = flat(cache[name]).at[wf].set(
                     val.reshape(-1, *val.shape[2:]))
                 new_cache[name] = fl.reshape(cache[name].shape)
-            hkv = cache["k_hi"].shape[2]
-            msz = rt.mesh.shape["model"] \
-                if rt.mesh is not None and "model" in rt.mesh.axis_names \
-                else 1
-            # x.shape[1] == 1 also routes speculative VERIFICATION
-            # chunks (C=K+1 per-row drafts) to the ref gather path
-            # below — the kernel is single-query-per-row by
-            # construction. Speculation therefore still works under
-            # attn_backend="pallas", but draftful steps verify through
-            # the ref path (kernel-vs-ref rounding ~1e-6), so the
-            # bit-exact speculation-on/off sweeps run on the ref
-            # backend.
-            if rt.attn_backend == "pallas" and x.shape[1] == 1 \
-                    and hkv % msz == 0:
-                # single-token decode over planar blocks: hand the block
-                # table straight to the scalar-prefetch Pallas kernel —
-                # no (B, Cap) logical gather is ever materialized. The
-                # table is recovered from phys_read (= table ⊗ BS + offs)
-                # by striding; the scanned per-layer window rides as a
-                # traced (1,) operand so one executable serves a mixed
-                # local/global stack. Interpret mode off-TPU keeps the
-                # path runnable (and CI-testable) on CPU. Under a
-                # serving mesh the kernel runs inside shard_map on
-                # per-shard head slices (KV heads over `model`; q heads
-                # follow since H = Hkv·G); when kv_heads does not divide
-                # the axis the `hkv % msz` guard above routes decode to
-                # the GSPMD-partitionable ref gather instead.
+            if x.shape[1] == 1 and paged_decode_kernel(
+                    rt, cache["k_hi"].shape[2]):
+                # single-token decode over planar blocks: the block table
+                # goes straight to the scalar-prefetch kernel, so no
+                # (B, Cap) logical gather is materialized
                 from repro.kernels.planar_decode_attention import (
                     paged_planar_decode_attention)
-                bs_tok = cache["k_hi"].shape[1]
-                tables = phys_read[:, ::bs_tok] // bs_tok        # (B, MB)
-                wa = None
-                if window is not None:
-                    wa = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
-                fp8 = rt.mode == "fp8"
-                interp = jax.default_backend() != "tpu"
-                if msz > 1:
-                    # window placeholder must be concrete for shard_map
-                    # (0 = global; arithmetic-identical to None)
-                    wa0 = wa if wa is not None \
-                        else jnp.zeros((1,), jnp.int32)
-
-                    def _local(qq, kh, kl, vh, vl, tb, ln, w):
-                        return paged_planar_decode_attention(
-                            qq, kh, kl, vh, vl, tb, ln, fp8=fp8,
-                            window_arr=w, interpret=interp)
-                    pool = P(None, None, "model", None)
-                    o = jax.shard_map(
-                        _local, mesh=rt.mesh,
-                        in_specs=(P(None, "model", None), pool, pool,
-                                  pool, pool, P(None, None), P(None),
-                                  P(None)),
-                        out_specs=P(None, "model", None),
-                        check_vma=False)(
-                        q[:, 0], new_cache["k_hi"], new_cache["k_lo"],
-                        new_cache["v_hi"], new_cache["v_lo"], tables,
-                        _as_lens(kv_len, b), wa0)[:, None]
-                else:
-                    o = paged_planar_decode_attention(
-                        q[:, 0], new_cache["k_hi"], new_cache["k_lo"],
-                        new_cache["v_hi"], new_cache["v_lo"], tables,
-                        _as_lens(kv_len, b), fp8=fp8, window_arr=wa,
-                        interpret=interp)[:, None]
-                o = o.reshape(b, x.shape[1], -1).astype(rt.dtype)
+                pool = P(None, None, "model", None)
+                o = _decode_through_kernel(
+                    rt, functools.partial(paged_planar_decode_attention,
+                                          fp8=rt.mode == "fp8"),
+                    q[:, 0], [new_cache[n] for n in
+                              ("k_hi", "k_lo", "v_hi", "v_lo")],
+                    [pool] * 4, phys_read, kv_len, window)
+                o = o.reshape(b, 1, -1).astype(rt.dtype)
                 return apply_linear(rt, p["wo"], o), new_cache
             if rt.mode == "fp8":
                 kc = e5m2_view(flat(new_cache["k_hi"])[phys_read], jnp.float16)
@@ -467,13 +469,31 @@ def attention(rt: Runtime, p: dict, cfg, x: jax.Array, *,
                 vc = join_bytes(flat(new_cache["v_hi"])[phys_read],
                                 flat(new_cache["v_lo"])[phys_read])
         else:
-            kf = flat(cache["k"]).at[wf].set(
-                k.astype(cache["k"].dtype).reshape(-1, *k.shape[2:]))
-            vf = flat(cache["v"]).at[wf].set(
-                v.astype(cache["v"].dtype).reshape(-1, *v.shape[2:]))
+            # plain pool (NB, BS, Hkv*D) of f16 bit patterns as int16
+            # (model.PLAIN_KV_DTYPE): a token's KV heads side by side on
+            # the lane axis, so a block is one contiguous run in HBM
+            # whatever the head size
+            hkv, hd = k.shape[2], k.shape[3]
+            bits = lambda a: jax.lax.bitcast_convert_type(  # noqa: E731
+                a.astype(jnp.float16), jnp.int16).reshape(-1, hkv * hd)
+            kf = flat(cache["k"]).at[wf].set(bits(k))
+            vf = flat(cache["v"]).at[wf].set(bits(v))
             new_cache = {"k": kf.reshape(cache["k"].shape),
                          "v": vf.reshape(cache["v"].shape)}
-            kc, vc = kf[phys_read], vf[phys_read]
+            if x.shape[1] == 1 and paged_decode_kernel(rt, hkv):
+                # single-token decode reads only each row's live blocks
+                from repro.kernels.paged_decode_attention import (
+                    paged_decode_attention)
+                o = _decode_through_kernel(
+                    rt, paged_decode_attention, q[:, 0],
+                    [new_cache["k"], new_cache["v"]],
+                    [P(None, None, "model")] * 2, phys_read, kv_len,
+                    window)
+                o = o.reshape(b, 1, -1).astype(rt.dtype)
+                return apply_linear(rt, p["wo"], o), new_cache
+            kc, vc = (jax.lax.bitcast_convert_type(
+                a[phys_read], jnp.float16).reshape(b, -1, hkv, hd)
+                for a in (kf, vf))
         o = attn_core_paged(q, kc, vc, q_offset=q_offset,
                             kv_len=_as_lens(kv_len, b), window=window)
     elif phase == "decode":

@@ -166,6 +166,10 @@ def init_cache(cfg: ArchConfig, batch: int, capacity: int,
     raise ValueError(fam)
 
 
+# storage of the plain GQA K/V planes: f16 values as their bit patterns
+PLAIN_KV_DTYPE = "int16"
+
+
 def cache_descriptor(cfg: ArchConfig, planar: bool = False) -> "KV.CacheDescriptor":
     """Per-family serving cache descriptor (serving/kvcache.py): which
     planes are block-paged and which are slot-resident, with per-token /
@@ -202,9 +206,16 @@ def cache_descriptor(cfg: ArchConfig, planar: bool = False) -> "KV.CacheDescript
             return KV.CacheDescriptor("gqa", planes=tuple(
                 KV.PlaneSpec(n, cfg.n_layers, (hkv, hd), "uint8")
                 for n in ("k_hi", "k_lo", "v_hi", "v_lo")), groups=groups)
+        # plain planes hold f16 K/V as int16 bit patterns, a token's KV
+        # heads side by side on one axis (layers.attention "paged"): a
+        # block is one contiguous run of the TPU's tiled layout at any
+        # head size, and the decode kernel takes the pool as it is (Mosaic
+        # takes no f16 operand, and XLA copies a whole array to view f16
+        # as int16)
         return KV.CacheDescriptor("gqa", planes=(
-            KV.PlaneSpec("k", cfg.n_layers, (hkv, hd), cd),
-            KV.PlaneSpec("v", cfg.n_layers, (hkv, hd), cd)), groups=groups)
+            KV.PlaneSpec("k", cfg.n_layers, (hkv * hd,), PLAIN_KV_DTYPE),
+            KV.PlaneSpec("v", cfg.n_layers, (hkv * hd,), PLAIN_KV_DTYPE)),
+            groups=groups)
     if planar:
         raise ValueError("byte-planar NestedKV applies to GQA K/V planes "
                          "only, not SSM/hybrid state")
@@ -233,8 +244,8 @@ def cache_descriptor(cfg: ArchConfig, planar: bool = False) -> "KV.CacheDescript
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     return KV.CacheDescriptor(
         "hybrid",
-        planes=(KV.PlaneSpec("k", n_apps, (hkv, hd), cd),
-                KV.PlaneSpec("v", n_apps, (hkv, hd), cd)),
+        planes=(KV.PlaneSpec("k", n_apps, (hkv * hd,), PLAIN_KV_DTYPE),
+                KV.PlaneSpec("v", n_apps, (hkv * hd,), PLAIN_KV_DTYPE)),
         slot_planes=slot_planes, prefix_cacheable=False)
 
 

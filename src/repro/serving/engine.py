@@ -120,10 +120,11 @@ turns them into the `engine_dispatch/*` rows the CI smoke asserts.
 Profiler spans: every step is a `jax.profiler` step span `engine.step`
 holding one span per phase that has work — `engine.restore`,
 `engine.schedule`, `engine.prefill` (`mode`, `rows`, `tokens`),
-`engine.decode` (`mode`, `rows`), `engine.sync` (the device->host pulls
-alone), `engine.finalize`, and `engine.spill` (`blocks`) wherever a spill
-capture runs — so a trace of the serving process puts each gap in the
-device's timeline down to the phase the host was in.
+`engine.decode` (`mode`, `rows`, `attn`: "pallas" or "ref"), `engine.sync`
+(the device->host pulls alone), `engine.finalize`, and `engine.spill`
+(`blocks`) wherever a spill capture runs — so a trace of the serving
+process puts each gap in the device's timeline down to the phase the
+host was in.
 """
 
 from __future__ import annotations
@@ -147,7 +148,8 @@ from repro.core.policy import (AdaptiveKController, DualPrecisionController,
                                RestorePolicy, SpeculationConfig,
                                StepObservation)
 from repro.models import model as M
-from repro.models.layers import Runtime
+from repro.kernels import ops as OPS
+from repro.models.layers import Runtime, paged_decode_kernel
 from repro.serving import shard as SHARD
 from repro.serving.kvcache import (TRASH_BLOCK, BlockManager, HostPool,
                                    SlotManager)
@@ -211,7 +213,7 @@ class Engine:
     def __init__(self, cfg: ArchConfig, serving_params, *, n_slots: int,
                  capacity: int, controller: DualPrecisionController | None = None,
                  forced_mode: str | None = None, backend: str | None = None,
-                 attn_backend: str = "ref",
+                 attn_backend: str | None = None,
                  kv_planar: bool = False,
                  clock: Callable[[], float] = time.monotonic,
                  block_size: int = 16,
@@ -289,6 +291,9 @@ class Engine:
                       # tables are counted by BlockManager separately)
                       "prefill_dispatches": 0, "decode_dispatches": 0,
                       "aux_dispatches": 0, "h2d_bytes": 0,
+                      # plain C=1 decode dispatches whose program runs a
+                      # Pallas attention kernel (`decode_attn`)
+                      "attn_kernel_decodes": 0,
                       # speculative decoding (spec_stats() / bench
                       # spec/* rows): decode_rows counts row-dispatches,
                       # decode_tokens the tokens they emitted — their
@@ -326,19 +331,28 @@ class Engine:
         self.last_mode: str | None = None
         # backend=None lets the platform pick the GEMMs
         # (ops.default_backend): the NestedFP Pallas kernels on TPU, the
-        # jnp oracle on CPU.
-        # attn_backend="pallas" serves planar GQA decode through the
-        # block-table scalar-prefetch kernel (layers.attention "paged");
-        # anything it cannot serve falls back to the ref gather path.
+        # jnp oracle on CPU. attn_backend=None lets it pick the paged
+        # decode attention the same way: "pallas" serves C=1 decode over
+        # GQA pools through a block-table kernel that reads only each
+        # row's live blocks (layers.attention "paged"); prefill chunks,
+        # speculative verification and MLA keep the ref gather path,
+        # which "ref" forces everywhere.
         # act_quant="per_token": fp8 generation must be batch-invariant
         # under continuous batching (and speculative verification chunks)
         # — per-tensor dynamic scales would couple co-batched tokens'
         # rounding (Runtime docstring).
+        attn_backend = attn_backend or OPS.default_backend()
         self._rts = {m: Runtime(mode=m, backend=backend, dtype=jnp.float32,
                                 act_quant="per_token",
                                 attn_backend=None if attn_backend == "ref"
                                 else attn_backend, mesh=mesh)
                      for m in ("fp16", "fp8")}
+        # what the plain C=1 decode program runs for attention: a Pallas
+        # kernel over the GQA pool, or the gather (the `engine.decode`
+        # span's `attn`, counted in stats["attn_kernel_decodes"])
+        self.decode_attn = "pallas" if self.desc.kind in ("gqa", "hybrid") \
+            and paged_decode_kernel(self._rts["fp16"], cfg.n_kv_heads) \
+            else "ref"
         self.block_size = block_size
         mbs = -(-capacity // block_size)
         # per-layer-group window metadata: sliding-window archs (gemma3)
@@ -971,7 +985,7 @@ class Engine:
                    tokens=tokens):
             chunk_ids = self._run_chunks(mode, plan, pending, fresh)
         with _span("engine.decode", bool(self.active), mode=mode,
-                   rows=len(self.active)):
+                   rows=len(self.active), attn=self.decode_attn):
             decode_ids, drafts = self._decode_paged(mode, chunk_ids, fresh)
         self._finalize_step(mode, pending, decode_ids, drafts)
         self._sample_peak()
@@ -1465,6 +1479,7 @@ class Engine:
             self.params, self.caches, toks, self.blocks.device_tables(),
             self._h2d(q_off), self._h2d(kvl))
         self.stats["decode_dispatches"] += 1
+        self.stats["attn_kernel_decodes"] += self.decode_attn == "pallas"
         return ids, None
 
     # nfp: sync-point

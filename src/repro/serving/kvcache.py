@@ -135,7 +135,8 @@ class PlaneSpec:
     """One token-granular cache plane, paged into fixed-size blocks.
 
     A pool leaf is shaped (n_layers, n_total_blocks, block_size,
-    *token_shape); `token_shape` is the per-token feature shape (GQA:
+    *token_shape); `token_shape` is the per-token feature shape (plain
+    GQA K/V, f16 bits as int16: (Hkv*Hd,); NestedKV byte planes:
     (Hkv, Hd); MLA c_kv: (kv_lora_rank,))."""
     name: str
     n_layers: int

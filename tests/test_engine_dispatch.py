@@ -259,6 +259,29 @@ class TestPallasBackend:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
+    def test_plain_pool_kernel_serves_the_same_tokens(self, tiny):
+        """attn_backend='pallas' on the plain f16 pool: every C=1 decode
+        runs the live-block kernel (interpret mode here) and greedy
+        tokens match the gather path's; the CPU's default stays the
+        gather, with the counter at 0."""
+        cfg, sparams = tiny
+        out, engines = {}, {}
+        for backend in ("pallas", "ref", None):
+            eng = Engine(cfg, sparams, n_slots=4, capacity=64,
+                         forced_mode="fp16", attn_backend=backend)
+            for i, p in enumerate(PROMPTS):
+                eng.submit(Request(f"r{i}", p, max_new=6))
+            out[backend] = {r.request_id: r.output for r in eng.run()}
+            engines[backend] = eng
+        assert out["pallas"] == out["ref"] == out[None]
+        kern = engines["pallas"]
+        assert kern.decode_attn == "pallas"
+        assert kern.stats["attn_kernel_decodes"] \
+            == kern.stats["decode_dispatches"] > 0
+        for backend in ("ref", None):
+            assert engines[backend].decode_attn == "ref"
+            assert engines[backend].stats["attn_kernel_decodes"] == 0
+
     def test_engine_serves_end_to_end_with_pallas(self, tiny):
         """Interpret-mode Pallas decode through the full engine (the CI
         fast lane's 'backend runs green' check)."""

@@ -108,8 +108,8 @@ def test_prefill_and_decode_spans_carry_the_step_shapes(served):
             assert (pre[0]["rows"], pre[0]["tokens"], pre[0]["mode"]) == \
                 (d["chunks"], d["chunk_tokens"], "fp16")
         if dec:
-            assert (dec[0]["rows"], dec[0]["mode"]) == \
-                (d["decode_rows"], "fp16")
+            assert (dec[0]["rows"], dec[0]["mode"], dec[0]["attn"]) == \
+                (d["decode_rows"], "fp16", "ref")
         seen.add((bool(pre), bool(dec)))
     # the run holds a prefill-only, a mixed and a decode-only step
     assert seen >= {(True, False), (True, True), (False, True)}
@@ -165,3 +165,20 @@ def test_spill_and_restore_spans_only_where_the_host_tier_works(tmp_path):
     assert len(restores) < len(steps)       # none in a step with no work
     for _, s, e, _ in spills + restores:
         assert any(a <= s and e <= b for _, a, b, _ in steps)
+
+
+def test_decode_span_names_the_attention_kernel(tmp_path):
+    """Under attn_backend="pallas" every `engine.decode` span says so,
+    and each plain decode dispatch is counted as a kernel decode."""
+    cfg = ARCHS["qwen1.5-0.5b"].reduced()
+    sparams = to_serving(M.init_params(jax.random.PRNGKey(0), cfg))
+    eng = Engine(cfg, sparams, n_slots=2, capacity=64, forced_mode="fp16",
+                 attn_backend="pallas")
+    for i, p in enumerate(PROMPTS[:2]):
+        eng.submit(Request(f"r{i}", p, max_new=3))
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    dec = [a for n, _, _, a in _host_events(tmp_path) if n == "engine.decode"]
+    assert dec and all(a["attn"] == "pallas" for a in dec)
+    assert eng.stats["attn_kernel_decodes"] \
+        == eng.stats["decode_dispatches"] == len(dec)

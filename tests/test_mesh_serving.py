@@ -189,6 +189,24 @@ class TestMeshParity:
         got, _ = _serve(cfg, sp, mesh, req(), **kw)
         assert got == ref
 
+    @pytest.mark.parametrize("planar", [False, True],
+                             ids=["plain", "planar"])
+    def test_pallas_decode_shard_map_bit_exact_per_pool(self, tiny, mesh,
+                                                         planar):
+        """Both decode kernels under the mesh in FP16: the plain pool's
+        merged head axis splits on head boundaries, so each shard's
+        kernel sees whole heads and agrees with one device."""
+        cfg, sp = tiny
+        kw = dict(n_slots=2, capacity=64, forced_mode="fp16",
+                  kv_planar=planar, attn_backend="pallas",
+                  prefix_cache=False)
+        req = lambda: [Request("p", list(range(5, 18)), max_new=3)]
+        ref, _ = _serve(cfg, sp, None, req(), **kw)
+        got, eng = _serve(cfg, sp, mesh, req(), **kw)
+        assert got == ref
+        assert eng.stats["attn_kernel_decodes"] \
+            == eng.stats["decode_dispatches"] > 0
+
     def test_pallas_gemms_shard_map_match_single_device(self, tiny, mesh):
         """Pallas GEMM backend under the mesh: each nested linear runs
         inside shard_map on its (K, N) slice — column-parallel q/k/v/gate/

@@ -162,6 +162,13 @@ class TestPagedCacheSpec:
         assert self._spec(("attn", "k_hi"), (2, 64, 16, 4, 64)) \
             == P(None, None, None, "model", None)
 
+    @pytest.mark.parametrize("pool", ["attn", "shared"])
+    def test_plain_pool_shards_its_merged_head_axis(self, pool):
+        # (L, NB, BS, Hkv*Hd): qwen1.5-0.5b's 16 kv heads x 64 split on
+        # head boundaries over 4 shards
+        assert self._spec((pool, "k"), (24, 64, 16, 1024)) \
+            == P(None, None, None, "model")
+
     def test_indivisible_kv_heads_replicate(self):
         # gemma3-style 1 kv head: replicated pool, matching the
         # projection fallback above

@@ -5,8 +5,9 @@ The TPU compiler is installed with JAX and compiles for a described
 vector types, 16-bit integer shifts, unaligned tiles, VMEM limits); this
 file can. Shapes are the published GEMM widths of qwen1.5-0.5b and
 llama3.1-8b at a decode batch (M=8) and a prefill chunk (M=256), the
-paged planar decode kernel in both modes, and one whole full-width
-qwen1.5-0.5b `paged_step` per mode.
+paged planar decode kernel in both modes, the plain-pool paged decode
+kernel at the benchmark cells' shapes, and one whole full-width
+qwen1.5-0.5b `paged_step` per mode with the Pallas GEMMs and attention.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every pytest worker
@@ -22,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.kernels import ops
+from repro.kernels.paged_decode_attention import paged_decode_attention
 from repro.kernels.planar_decode_attention import paged_planar_decode_attention
 from repro.models import model as M
 from repro.models.convert import serving_param_shapes
@@ -108,24 +110,45 @@ def test_paged_planar_decode_compiles(one_chip, fp8):
         _spec(one_chip, (b, mb), jnp.int32), _spec(one_chip, (b,), jnp.int32))
 
 
+@pytest.mark.parametrize("shape", [(64, 16, 16, 64, 2561, 96),
+                                   (16, 56, 8, 128, 2049, 224)],
+                         ids=["qwen05b", "dsc33b-8l"])
+def test_paged_decode_compiles(one_chip, shape):
+    """The plain-pool decode kernel at the benchmark cells' shapes (rows,
+    q heads, kv heads, head dim, pool blocks of 16, table width): 64
+    slots of capacity 1536 with 16/16 heads x 64, and 16 slots of
+    capacity 3584 with 56/8 heads x 128."""
+    b, h, hkv, d, nb, mb = shape
+    pool = _spec(one_chip, (nb, 16, hkv * d), jnp.int16)
+    _compile(lambda q, k, v, t, n, w: paged_decode_attention(
+        q, k, v, t, n, window_arr=w),
+        _spec(one_chip, (b, h, d), jnp.float32), pool, pool,
+        _spec(one_chip, (b, mb), jnp.int32), _spec(one_chip, (b,), jnp.int32),
+        _spec(one_chip, (1,), jnp.int32))
+
+
 @pytest.mark.parametrize("mode", ["fp16", "fp8"])
-def test_full_width_paged_step_compiles(one_chip, mode):
+def test_full_width_paged_step_compiles(one_chip, mode, monkeypatch):
     """One batched decode step of qwen1.5-0.5b at published width: every
-    linear of the scanned layer is a Pallas GEMM, and the program fits a
-    16 GB chip."""
+    linear of the scanned layer is a Pallas GEMM, attention is the paged
+    decode kernel, and the program fits a 16 GB chip."""
     cfg = get_arch("qwen1.5-0.5b")
     place = lambda t: jax.tree.map(
         lambda s: _spec(one_chip, s.shape, s.dtype), t)
     params = place(serving_param_shapes(cfg))
     caches = place(jax.eval_shape(lambda: M.init_paged_cache(cfg, 257, 16)))
     rt = Runtime(mode=mode, backend="pallas", dtype=jnp.float32,
-                 act_quant="per_token")
+                 act_quant="per_token", attn_backend="pallas")
     i32 = lambda *s: _spec(one_chip, s, jnp.int32)
+    # the process's platform is the CPU, where the attention kernel would
+    # run interpreted: lower it as the chip runs it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     c = jax.jit(lambda p, ca, t, tab, qo, kvl: M.paged_step(
         rt, p, cfg, t, ca, tab, q_offset=qo, kv_len=kvl, block_size=16),
         donate_argnums=(1,)).lower(
             params, caches, i32(8, 1), i32(8, 32), i32(8), i32(8)).compile()
-    assert c.as_text().count("tpu_custom_call") >= 7    # q k v o gate up down
+    # q k v o gate up down, and the attention kernel
+    assert c.as_text().count("tpu_custom_call") >= 8
     mem = c.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
