@@ -8,8 +8,9 @@ attributed by the
 harness span it ran in: every `nestedfp16_matmul` custom call inside a
 `bench.step` span of FP16 mode is an FP16 call. The step's records give
 the calls' shapes: a fused prefill program over rows x chunk bucket rows
-and a decode program over all slots, each running every layer's seven
-linears once."""
+and a decode program over all slots, each running every layer's
+linears once. On a mesh the calls' device time is device 0's, set
+against each chip's even share of the work (`work.roofline_s`)."""
 
 from bench import reduce as R
 from bench import work
@@ -36,8 +37,10 @@ def read(run):
         kernel += t
         if step["prefill"]:
             rb, cb = step["prefill"]
-            bound += work.step_gemm_roofline_s(cfg, rb * cb, MODE, run["peak"])
+            bound += work.step_gemm_roofline_s(cfg, rb * cb, MODE,
+                                               run["peak"], run["chips"])
         if step["decode_rows"]:
             bound += work.step_gemm_roofline_s(
-                cfg, cfg["engine"]["n_slots"], MODE, run["peak"])
+                cfg, cfg["engine"]["n_slots"], MODE, run["peak"],
+                run["chips"])
     return 100.0 * bound / kernel if kernel else None

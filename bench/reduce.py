@@ -3,7 +3,8 @@ and idle gaps.
 
 `load` keeps three things of a trace: the operations that ran on each
 device (the "XLA Ops" line of every `/device:` plane, without the
-`while`/`conditional`/`call` ops that only contain others), the
+`while`/`conditional`/`call` ops that only contain others), sorted by
+start and with their starts apart in `op_starts` for bisection; the
 harness's own host spans (events named `bench.*` on the host plane, with
 their arguments) and the number of devices. An operation is named by its
 HLO instruction without the numeric suffix: `%nestedfp16_matmul.43 =
@@ -56,7 +57,8 @@ def load(path: str) -> dict:
                                       dict(e.stats)))
     ops.sort(key=lambda o: o[1])
     spans.sort(key=lambda o: o[1])
-    return {"ops": ops, "spans": spans, "n_devices": n_dev}
+    return {"ops": ops, "op_starts": [o[1] for o in ops], "spans": spans,
+            "n_devices": n_dev}
 
 
 def union(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -137,6 +139,12 @@ def breakdown(tr: dict, lo: float, hi: float, top: int = 10) -> dict:
 
 def ops_in(tr: dict, lo: float, hi: float, match) -> list[tuple[str, float]]:
     """(name, seconds) of device-0 operations inside [lo, hi] whose name
-    satisfies `match`."""
-    return [(n, e - s) for n, s, e, dev in tr["ops"]
-            if dev == 0 and s >= lo and e <= hi and match(n)]
+    satisfies `match`. The operations are sorted by start, so only those
+    that start in [lo, hi] are looked at: a reader that asks once per
+    step span reads each operation about once, not once per span."""
+    ops = tr["ops"]
+    starts = tr.get("op_starts") or [o[1] for o in ops]
+    first = bisect.bisect_left(starts, lo)
+    last = bisect.bisect_right(starts, hi)
+    return [(n, e - s) for n, s, e, dev in ops[first:last]
+            if dev == 0 and e <= hi and match(n)]

@@ -4,17 +4,22 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of `workloads` in BENCHMARK.json: a configuration
-(`bench/configs/<config>.json`, with the plain reference it names beside
-it) under a traffic mix (`bench/traffic/<mix>.json`). Metrics are
-readers in `bench/metrics/<metric>.py`, found by the names BENCHMARK.json
-gives the cell. Nothing here knows a cell, a model or a metric by name.
+(`bench/configs/<config>.json`, with the family module it names beside
+it) under a traffic mix (`bench/traffic/<mix>.json`) on the chips it asks
+for. The family module (`bench/family.py`) owns the architecture: the
+program's ArchConfig, the weights' leaves, the serving tree and the
+plain reference. Metrics are readers in `bench/metrics/<metric>.py`,
+found by the names BENCHMARK.json gives the cell. Nothing here knows a
+cell, a model, an architecture or a metric by name.
 
 One run:
   1. finds the chips the cell asks for (no TPU: exit 3, no result);
   2. turns on the persistent compilation cache (`<checkout>/.jax_cache`
      unless JAX_COMPILATION_CACHE_DIR is set);
-  3. draws the weights on the device from --seed (`bench/weights.py`);
-  4. builds an `Engine` with the configuration's sizes and the mix's mode;
+  3. draws the weights on the device from --seed (`bench/weights.py`),
+     on more than one chip each chip its own slice of a serving mesh;
+  4. builds an `Engine` with the configuration's sizes and the mix's mode
+     (on that mesh where the cell asks for more than one chip);
   5. runs every step program the cell's traffic can reach once, so that
      all of them are compiled (or loaded from the cache) in set-up;
   6. drives the mix open-loop from --seed: `warm_s` seconds outside the
@@ -53,7 +58,6 @@ PROCESS_START = time.perf_counter()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -65,6 +69,8 @@ import numpy as np  # noqa: E402
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+
+from bench.family import load as load_module  # noqa: E402
 
 # Correctness sample: the longest finished request and then others drawn
 # from the seed, until this many served tokens are covered.
@@ -79,14 +85,6 @@ class NoChip(RuntimeError):
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def cell_spec(workload: str, bench_json: Path = ROOT / "BENCHMARK.json"):
@@ -107,28 +105,15 @@ def cell_spec(workload: str, bench_json: Path = ROOT / "BENCHMARK.json"):
     return cell, cfg, mix, mine(bj["end_to_end"]), mine(bj["per_layer"])
 
 
-def arch_config(cfg: dict):
-    """The program's ArchConfig for a configuration file."""
-    from repro.configs.base import ArchConfig
-    return ArchConfig(
-        arch_id=cfg["name"], family=cfg["family"],
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=bool(cfg.get("attention_bias")),
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        max_seq_len=cfg["max_position_embeddings"])
-
-
 def find_chips(chips: int, allow_cpu: bool = False) -> list:
+    """The first `chips` devices; no TPU, or fewer chips than that: NoChip
+    (`allow_cpu`, for the tests: any platform)."""
     import jax
     devs = jax.devices()
-    if not allow_cpu and (devs[0].platform != "tpu" or len(devs) < chips):
+    if len(devs) < chips or not (allow_cpu or devs[0].platform == "tpu"):
         raise NoChip(f"this cell needs {chips} TPU chip(s); JAX found "
                      f"{len(devs)} {devs[0].platform} device(s)")
-    return devs
+    return devs[:chips]
 
 
 def pow2_upto(n: int) -> list[int]:
@@ -178,9 +163,11 @@ class Recorder:
         return rec
 
 
-def warm_up(eng, mode: str, cfg: dict, mix: dict) -> int:
+def warm_up(eng, mode: str, mix: dict) -> int:
     """Run once every program the cell's traffic can reach, with inputs
-    that write only to the trash block; returns how many were run.
+    that write only to the trash block and placed as the engine places
+    them; returns how many were run. On a mesh, call it under the mesh's
+    context, as `Engine.step` runs.
 
     Prefill rows per step: at most one prefill carried over from the
     last step, one cut short by the chunk budget, and whole prompts of at
@@ -214,6 +201,15 @@ def warm_up(eng, mode: str, cfg: dict, mix: dict) -> int:
             for k in range(1, rb + 1):
                 eng._overlay(toks, zeros(k), ids, zeros(k))
                 count += 1
+    if eng.mesh is not None:
+        # on a mesh, decode tokens with a prefill's first token overlaid
+        # are committed replicated: another program than tokens fresh
+        # from the host (on one device the two are the same program)
+        ids, eng.caches = eng._decode[mode](
+            eng.params, eng.caches, eng._overlay(toks, zeros(1), ids,
+                                                 zeros(1)),
+            tables, zeros(n_slots), zeros(n_slots))
+        count += 1
     # block-table flushes: pow2 update counts up to a whole table
     for kb in pow2_upto(n_slots * mb):
         idx = np.zeros((kb, 3), np.int32)
@@ -226,7 +222,7 @@ def warm_up(eng, mode: str, cfg: dict, mix: dict) -> int:
     most = mb + n_slots + budget // eng.block_size + max_rows
     for g, gather in getattr(eng, "_spill_gather", {}).items():
         for kb in pow2_upto(most):
-            gather(eng.caches, jnp.asarray(np.zeros(kb, np.int32)))
+            gather(eng.caches, eng._tier_dev(np.zeros(kb, np.int32)))
             count += 1
     import jax
     jax.block_until_ready(eng.caches)
@@ -381,7 +377,8 @@ def sample_finished(reqs: list[dict], seed: int) -> list[dict]:
 def check(cfg: dict, seed: int, picked: list[dict]) -> dict:
     """The widest gap, over the sampled requests' served tokens, by which
     a served token's reference logit lies below the reference's best."""
-    ref = load_module(BENCH / "configs" / f"{cfg['reference']}.py")
+    from bench import family
+    ref = family.of(cfg)
     seqs = [(r["req"].tokens, r["req"].output) for r in picked]
     in_vocab = all(0 <= t < cfg["vocab_size"] for _, o in seqs for t in o)
     t0 = time.perf_counter()
@@ -395,10 +392,10 @@ def check(cfg: dict, seed: int, picked: list[dict]) -> dict:
 
 # -- one run ----------------------------------------------------------------
 
-def peak_bytes() -> int | None:
-    import jax
+def peak_bytes(devs) -> int | None:
+    """The peak bytes in use on the fullest of `devs`."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in jax.local_devices()]
+             for d in devs]
     peaks = [p for p in peaks if p is not None]
     return max(peaks) if peaks else None
 
@@ -408,7 +405,8 @@ def run_once(workload: str, seed: int, seconds: float, trace: bool, *,
              bench_json: Path = ROOT / "BENCHMARK.json",
              allow_cpu: bool = False, log=print) -> dict:
     import jax
-    from bench import traffic, weights
+    from bench import family, traffic, weights
+    from repro.core.compat import mesh_context
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.engine import Engine
 
@@ -422,14 +420,22 @@ def run_once(workload: str, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter()
     mode = mode or mix["mode"]
     es = cfg["engine"]
-    params = weights.serving_params(seed, cfg)
+    chips = len(devs)
+    mesh = None
+    if chips > 1:
+        from repro.launch.mesh import make_serving_mesh
+        mesh = make_serving_mesh(chips)
+    params = weights.serving_params(seed, cfg, mesh)
     jax.block_until_ready(params)
-    eng = Engine(arch_config(cfg), params, n_slots=es["n_slots"],
-                 capacity=es["capacity"], n_blocks=es["n_blocks"],
-                 block_size=es["block_size"],
-                 chunk_tokens=es["chunk_tokens"], forced_mode=mode)
+    eng = Engine(family.of(cfg).arch_config(cfg), params,
+                 n_slots=es["n_slots"], capacity=es["capacity"],
+                 n_blocks=es["n_blocks"], block_size=es["block_size"],
+                 chunk_tokens=es["chunk_tokens"], forced_mode=mode,
+                 mesh=mesh)
     rec = Recorder(eng)
-    n_programs = warm_up(eng, mode, cfg, mix)
+    with (contextlib.nullcontext() if mesh is None
+          else mesh_context(mesh)):
+        n_programs = warm_up(eng, mode, mix)
     arrivals = traffic.generate(
         mix, seed, mix["warm_s"] + seconds + mix["drain_s"],
         cfg["vocab_size"], rate_scale)
@@ -441,9 +447,9 @@ def run_once(workload: str, seed: int, seconds: float, trace: bool, *,
     runrec = drive(eng, rec, arrivals, mix["warm_s"], seconds,
                    mix["drain_s"], tracer)
     counter.armed = False
-    mem = peak_bytes()
+    mem = peak_bytes(devs)
     stats = dict(eng.stats)
-    del eng, rec, params
+    del eng, rec, params, mesh
     gc.collect()
     tr = tracer.reduce() if tracer else None
     picked = sample_finished(runrec["requests"], seed)
@@ -453,7 +459,7 @@ def run_once(workload: str, seed: int, seconds: float, trace: bool, *,
     failed = sum(r["refused"] or (mix["drain_s"] > 0 and r["last"] is None)
                  for r in in_win)
     run = {"setup_s": setup_s, "seconds": seconds, "cfg": cfg, "mix": mix,
-           "peak": peak, **runrec, "trace": tr}
+           "peak": peak, "chips": chips, **runrec, "trace": tr}
     metrics, units = {}, {}
     bj = load_json(bench_json)
     for m in bj["end_to_end"] + bj["per_layer"]:
@@ -463,7 +469,7 @@ def run_once(workload: str, seed: int, seconds: float, trace: bool, *,
         if value is not None:
             metrics[name] = {"value": value, "unit": units[name]}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": mem}
+              "count": chips, "memory_peak_bytes": mem}
     out = {"correct": None, "attempted": len(in_win), "failed": failed,
            "metrics": metrics, "device": device}
     if tr is not None:

@@ -38,13 +38,14 @@ def step_flops(cfg: dict, step: dict) -> int:
 
 
 def step_mfu(run: dict) -> float | None:
-    """Model operations of the window's steps over what the chip's peak
-    could do in the window, in percent."""
+    """Model operations of the window's steps over what the peaks of the
+    run's chips could do in the window, in percent."""
     steps = window_steps(run)
     if not steps or run["peak"] is None:
         return None
     flops = sum(step_flops(run["cfg"], s) for s in steps)
-    return 100.0 * flops / (run["seconds"] * run["peak"]["flops_per_s"])
+    return 100.0 * flops / (run["seconds"] * run["chips"]
+                            * run["peak"]["flops_per_s"])
 
 
 def device_idle_share(run: dict) -> float | None:

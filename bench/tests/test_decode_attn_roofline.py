@@ -20,7 +20,7 @@ def run_of(ops):
              ("bench.step", 2.0, 3.0, {"mode": "fp8", "i": 2})]
     steps = [{"decode": [99, 199]}, {"decode": [399]}, {"decode": [9]}]
     return {"trace": {"ops": ops, "spans": spans, "n_devices": 1},
-            "cfg": CFG, "peak": PEAK, "steps": steps}
+            "cfg": CFG, "peak": PEAK, "chips": 1, "steps": steps}
 
 
 def test_share_of_live_bytes_over_kernel_time():

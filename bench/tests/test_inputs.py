@@ -7,7 +7,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from bench import traffic, weights
+from bench import family, traffic, weights
 
 DATA = Path(__file__).resolve().parent / "data"
 TRAFFIC = Path(__file__).resolve().parents[1] / "traffic"
@@ -16,19 +16,19 @@ TRAFFIC = Path(__file__).resolve().parents[1] / "traffic"
 def test_program_and_reference_weights_agree():
     from repro.core.nestedfp import decode
     from repro.models.convert import serving_param_shapes
-    import bench.run as R
     cfg = json.loads((DATA / "tiny.json").read_text())
+    fam = family.of(cfg)
     seed = 2**32 + 99
     tree = weights.serving_params(seed, cfg)
-    want = serving_param_shapes(R.arch_config(cfg))
+    want = serving_param_shapes(fam.arch_config(cfg))
     got_s = jax.tree.map(lambda a: (a.shape, a.dtype), tree)
     want_s = jax.tree.map(lambda a: (a.shape, a.dtype), want)
     assert jax.tree.structure(got_s) == jax.tree.structure(want_s)
     assert jax.tree.leaves(got_s) == jax.tree.leaves(want_s)
-    draw = weights.layer_values(cfg)
+    draw = fam.layer_values(cfg)
     for layer in range(cfg["num_hidden_layers"]):
         ref = draw(weights.root_key(seed), layer)
-        for name in weights.LINEARS:
+        for name in fam.LINEARS:
             part, leaf = name.split("/")
             w = tree["layers"][part][leaf].weight
             f16 = decode(w.upper[layer], w.lower[layer])
@@ -38,7 +38,7 @@ def test_program_and_reference_weights_agree():
                                       ref["ln1"])
         np.testing.assert_array_equal(
             tree["layers"]["attn"]["wq"].bias[layer], ref["attn/wq/b"])
-    glob = weights.global_values(seed, cfg)
+    glob = fam.global_values(seed, cfg)
     np.testing.assert_array_equal(tree["embed"]["tok"], glob["embed"])
     np.testing.assert_array_equal(tree["lm_head"]["w"], glob["lm_head"])
 

@@ -56,3 +56,14 @@ def test_recorded_chip_trace():
     inside = sum(any(s <= o[1] and o[2] <= e for s, e in steps)
                  for o in tr["ops"] if lo <= o[1] and o[2] <= hi)
     assert inside / sum(lo <= o[1] and o[2] <= hi for o in tr["ops"]) > 0.95
+
+
+@pytest.mark.skipif(not RECORDED.exists(), reason="no recorded trace")
+def test_ops_in_matches_a_scan_of_every_op():
+    # ops_in bisects the sorted starts; it finds what a scan finds
+    tr = R.load(str(RECORDED))
+    is_any = lambda n: True  # noqa: E731
+    for _, lo, hi, _ in tr["spans"]:
+        assert R.ops_in(tr, lo, hi, is_any) == [
+            (n, e - s) for n, s, e, dev in tr["ops"]
+            if dev == 0 and s >= lo and e <= hi]

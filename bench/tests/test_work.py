@@ -28,8 +28,9 @@ CASES = [
 @pytest.mark.parametrize("name,linear,m,flops,b16,b8", CASES)
 @pytest.mark.parametrize("mode", ["fp16", "fp8"])
 def test_gemm(name, linear, m, flops, b16, b8, mode):
-    from bench.weights import linear_shapes
-    k, n = linear_shapes(cfg(name))[linear]
+    from bench import family
+    c = cfg(name)
+    k, n = family.of(c).linear_shapes(c)[linear]
     assert work.gemm(m, k, n, mode) == (flops, b16 if mode == "fp16" else b8)
 
 
@@ -56,3 +57,21 @@ def test_roofline_picks_the_binding_bound():
     assert work.roofline_s(f, b, peak) == b / 819e9          # memory-bound
     f, b = work.gemm(4096, 7168, 7168, "fp16")
     assert work.roofline_s(f, b, peak) == f / 197e12         # compute-bound
+
+
+def test_per_chip_shares_are_a_quarter_on_four_chips():
+    # the same step records read on 4 chips: each chip's even share of the
+    # work, so step_mfu and every roofline time are exactly a quarter
+    from bench import spans
+    c = cfg("qwen05b")
+    peak = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    steps = [{"t1": 0.5, "chunks": [(0, 200), (64, 100)], "decode": [9, 300]},
+             {"t1": 1.5, "chunks": [], "decode": [10, 301, 5]}]
+    run = {"steps": steps, "w0": 0.0, "w1": 2.0, "seconds": 2.0,
+           "cfg": c, "peak": peak, "chips": 1}
+    assert spans.step_mfu({**run, "chips": 4}) == spans.step_mfu(run) / 4
+    for f, b in [work.gemm(32, 7168, 7168, "fp16"),
+                 work.gemm(4096, 7168, 7168, "fp16")]:
+        assert work.roofline_s(f, b, peak, 4) == work.roofline_s(f, b, peak) / 4
+    assert work.step_gemm_roofline_s(c, 64, "fp16", peak, 4) == \
+        work.step_gemm_roofline_s(c, 64, "fp16", peak) / 4

@@ -1,13 +1,17 @@
-"""Seeded weights of a dense GQA decoder, made by the benchmark.
+"""Seeded weights made by the benchmark, for any architecture.
 
 Every value is an integer times a power of two, drawn with
 `jax.random.randint`, so it is exact in float16 (linear weights) or
 float32 (embedding, LM head, norms, biases) and comes out bit for bit the
-same however XLA fuses the program that draws it. The harness draws the
-whole serving tree on the device in one jitted call (`serving_params`);
-the plain reference draws one layer at a time (`layer_values`) from the
-same keys, so both see the same numbers and the reference takes nothing
-that the program made.
+same however XLA fuses the program that draws it. Each leaf has a key of
+its own, `fold_in(root, crc32(name))`, so adding a leaf changes no other.
+
+The configuration's family module (`bench/family.py`) owns the leaves:
+their names, shapes and scales, and the program's serving tree built from
+them. This module draws that tree on the device in one jitted call
+(`serving_params`); the family's plain reference draws the same leaves
+one layer at a time from the same keys, so both see the same numbers and
+the reference takes nothing that the program made.
 
 Scales follow the usual initialisation: linear weights uniform with
 standard deviation 1/sqrt(fan_in), the embedding 0.02, biases 0.02, and
@@ -23,9 +27,6 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-LINEARS = ("attn/wq", "attn/wk", "attn/wv", "attn/wo",
-           "mlp/gate", "mlp/up", "mlp/down")
-
 
 def root_key(seed: int):
     """A key from a seed of any size up to 64 bits."""
@@ -33,7 +34,7 @@ def root_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def _leaf_key(key, name: str):
+def leaf_key(key, name: str):
     return jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
 
 
@@ -52,106 +53,18 @@ def draw(key, shape, std: float, dtype):
     return ints.astype(dtype) * jnp.asarray(step, dtype)
 
 
-def linear_shapes(cfg: dict) -> dict[str, tuple[int, int]]:
-    d, hd = cfg["hidden_size"], cfg["head_dim"]
-    h, hkv, f = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["intermediate_size"])
-    return {"attn/wq": (d, h * hd), "attn/wk": (d, hkv * hd),
-            "attn/wv": (d, hkv * hd), "attn/wo": (h * hd, d),
-            "mlp/gate": (d, f), "mlp/up": (d, f), "mlp/down": (f, d)}
-
-
-def layer_leaf(key, cfg: dict, name: str, layer):
-    """One layer's value of a per-layer leaf (`layer` may be traced)."""
-    k = jax.random.fold_in(_leaf_key(key, name), layer)
-    d = cfg["hidden_size"]
-    if name in LINEARS:
-        kk, n = linear_shapes(cfg)[name]
-        return draw(k, (kk, n), kk ** -0.5, jnp.float16)
-    if name.endswith("/b"):
-        n = linear_shapes(cfg)[name[:-2]][1]
-        return draw(k, (n,), 0.02, jnp.float32)
-    if name in ("ln1", "ln2"):
-        return draw(k, (d,), 0.1 / math.sqrt(3.0), jnp.float32)
-    raise KeyError(name)
-
-
-def layer_names(cfg: dict) -> list[str]:
-    names = ["ln1", "ln2", *LINEARS]
-    if cfg.get("attention_bias"):
-        names += [f"attn/{w}/b" for w in ("wq", "wk", "wv")]
-    return names
-
-
-def global_leaf(key, cfg: dict, name: str):
-    d, v = cfg["hidden_size"], cfg["vocab_size"]
-    k = _leaf_key(key, name)
-    if name == "embed":
-        return draw(k, (v, d), 0.02, jnp.float32)
-    if name == "lm_head":
-        return draw(k, (d, v), d ** -0.5, jnp.float32)
-    if name == "final_norm":
-        return draw(k, (d,), 0.1 / math.sqrt(3.0), jnp.float32)
-    raise KeyError(name)
-
-
-def layer_values(cfg: dict):
-    """A jitted function (root key, layer) -> that layer's weights as
-    float32 values, for the reference."""
-    return jax.jit(lambda key, layer: {
-        n: layer_leaf(key, cfg, n, layer).astype(jnp.float32)
-        for n in layer_names(cfg)})
-
-
-def global_values(seed: int, cfg: dict) -> dict[str, jax.Array]:
-    """Embedding, final norm and LM head (the embedding again when tied)."""
-    def draw_all(key):
-        out = {n: global_leaf(key, cfg, n) for n in ("embed", "final_norm")}
-        out["lm_head"] = out["embed"].T if cfg["tie_word_embeddings"] \
-            else global_leaf(key, cfg, "lm_head")
-        return out
-    return jax.jit(draw_all)(root_key(seed))
-
-
-def serving_params(seed: int, cfg: dict):
-    """The program's serving tree, drawn on the device in one jitted call:
-    float32 embedding, norms, biases and LM head; each linear weight's
-    float16 values nested into its two byte planes by the program's own
-    encoder. Layers are drawn one at a time inside the program (lax.map),
-    so no float32 copy of a stacked weight ever exists."""
-    return jax.jit(builder(cfg))(root_key(seed))
-
-
-def builder(cfg: dict):
-    """The function of a root key that `serving_params` jits."""
-    from repro.core.linear import NestedLinearParams
-    from repro.core.nestedfp import NestedTensor, encode
-
-    n_layers = cfg["num_hidden_layers"]
-    names = layer_names(cfg)
-
-    def build(key):
-        per = jax.lax.map(
-            lambda l: {n: (encode(layer_leaf(key, cfg, n, l))
-                           if n in LINEARS else layer_leaf(key, cfg, n, l))
-                       for n in names},
-            jnp.arange(n_layers))
-
-        def linear(name):
-            upper, lower = per[name]
-            return NestedLinearParams(
-                weight=NestedTensor(upper=upper, lower=lower, raw=None),
-                bias=per.get(f"{name}/b"))
-        layers = {"ln1": per["ln1"], "ln2": per["ln2"],
-                  "attn": {w: linear(f"attn/{w}")
-                           for w in ("wq", "wk", "wv", "wo")},
-                  "mlp": {w: linear(f"mlp/{w}")
-                          for w in ("gate", "up", "down")}}
-        tree = {"embed": {"tok": global_leaf(key, cfg, "embed")},
-                "final_norm": global_leaf(key, cfg, "final_norm"),
-                "layers": layers}
-        if not cfg["tie_word_embeddings"]:
-            tree["lm_head"] = {"w": global_leaf(key, cfg, "lm_head")}
-        return tree
-
-    return build
+def serving_params(seed: int, cfg: dict, mesh=None):
+    """The program's serving tree from the family's builder, drawn on the
+    device in one jitted call. On a mesh each chip computes only its own
+    slice, laid out as the program's serving shardings place it, so no
+    device ever holds the whole tree."""
+    from bench import family
+    fam = family.of(cfg)
+    build = fam.builder(cfg)
+    key = root_key(seed)
+    if mesh is None:
+        return jax.jit(build)(key)
+    from repro.serving.shard import serving_param_shardings
+    shardings = serving_param_shardings(jax.eval_shape(build, key),
+                                        fam.arch_config(cfg), mesh)
+    return jax.jit(build, out_shardings=shardings)(key)

@@ -11,11 +11,17 @@ so they stay the same when a kernel changes:
     GEMMs of every layer, 4*c*heads*head_dim operations of attention per
     layer (scores and weighted values), and, where its logits are taken,
     the LM head (2*d_model*vocab).
+
+The linears and their shapes are the configuration's family module's
+(`bench/family.py`). On a mesh of `chips` chips the work is split over
+them: a roofline time is that of each chip's even share (operations and
+bytes over `chips`), which no chip can beat, since an even share is the
+least the busiest chip can hold.
 """
 
 from __future__ import annotations
 
-from bench.weights import LINEARS, linear_shapes
+from bench import family
 
 WEIGHT_BYTES = {"fp16": 2, "fp8": 1}
 ACT_BYTES = {"fp16": 2, "fp8": 1}
@@ -30,22 +36,25 @@ def gemm(m: int, k: int, n: int, mode: str) -> tuple[int, int]:
     return flops, nbytes
 
 
-def roofline_s(flops: int, nbytes: int, peak: dict) -> float:
-    """The least time the chip could take: the larger of the compute and
-    the memory bound."""
-    return max(flops / peak["flops_per_s"], nbytes / peak["hbm_bytes_per_s"])
+def roofline_s(flops: int, nbytes: int, peak: dict, chips: int = 1) -> float:
+    """The least time a chip could take for its even share of the work
+    over `chips` chips: the larger of the compute and the memory bound."""
+    return max(flops / chips / peak["flops_per_s"],
+               nbytes / chips / peak["hbm_bytes_per_s"])
 
 
-def step_gemm_roofline_s(cfg: dict, m: int, mode: str, peak: dict) -> float:
+def step_gemm_roofline_s(cfg: dict, m: int, mode: str, peak: dict,
+                         chips: int = 1) -> float:
     """Roofline time of every nested GEMM of one step program over m rows
-    (all layers)."""
-    per_layer = sum(roofline_s(*gemm(m, k, n, mode), peak)
-                    for k, n in (linear_shapes(cfg)[w] for w in LINEARS))
+    (all layers), for each chip's even share over `chips` chips."""
+    per_layer = sum(roofline_s(*gemm(m, k, n, mode), peak, chips)
+                    for k, n in family.of(cfg).linear_shapes(cfg).values())
     return per_layer * cfg["num_hidden_layers"]
 
 
 def linear_flops_per_token(cfg: dict) -> int:
-    return 2 * sum(k * n for k, n in linear_shapes(cfg).values()) \
+    return 2 * sum(k * n for k, n in
+                   family.of(cfg).linear_shapes(cfg).values()) \
         * cfg["num_hidden_layers"]
 
 
